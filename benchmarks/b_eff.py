@@ -47,7 +47,7 @@ def modeled_rows():
 
 def measured_rows():
     import jax
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     import jax.numpy as jnp
     from functools import partial
     from jax.sharding import PartitionSpec as P
@@ -62,13 +62,13 @@ def measured_rows():
         return rows
 
     n = jax.device_count()
-    mesh = jax.make_mesh((n,), ("x",))
+    mesh = make_mesh((n,), ("x",))
     comm = Communicator.from_mesh(mesh, "x")
     from repro.core import collectives
     cfg = CommConfig()
     x = jnp.zeros((n, 1 << 14), jnp.float32)
 
-    @partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
     def ring_once(xs):
         return collectives.sendrecv(xs[0], comm.ring_perm(), comm, cfg)[None]
 

@@ -65,14 +65,14 @@ def _predicted_e2e_us(collective: str, cfg: CommConfig) -> float:
 
 def _bench_collective(collective: str, tag: str, cands) -> list:
     import jax
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     from repro.core.communicator import Communicator
     from repro.tune.db import TuneDB, TuneEntry, select_config, topology_key
     from repro.tune.space import config_to_dict
     from repro.tune import sweep as tune_sweep
 
     n = jax.device_count()
-    mesh = compat.make_mesh((n,), ("x",))
+    mesh = make_mesh((n,), ("x",))
     comm = Communicator.from_mesh(mesh, "x")
     topo = topology_key(mesh)
     db = TuneDB()

@@ -82,7 +82,7 @@ def tp_reduce_rows():
     import jax.numpy as jnp
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     from repro.models import layers
     from repro.models.common import MeshContext, ModelConfig, Runtime
 
@@ -90,7 +90,7 @@ def tp_reduce_rows():
     if n < 2:
         return [("lmcoll_tp_reduce", 0.0, "skipped_1device")]
     tp = min(4, n)
-    mesh = jax.make_mesh((tp,), ("model",))
+    mesh = make_mesh((tp,), ("model",))
     cfg_model = ModelConfig(name="bench", family="dense", n_layers=1,
                             d_model=D_MODEL, n_heads=4, n_kv_heads=4,
                             d_ff=D_FF, vocab_size=1024)
@@ -108,7 +108,7 @@ def tp_reduce_rows():
                                       data_sizes=()),
                      comm=cc)
 
-        @partial(compat.shard_map, mesh=mesh,
+        @partial(jax.shard_map, mesh=mesh,
                  in_specs=(P(None, "model"), P("model", None)),
                  out_specs=P(), check_vma=False)
         def f(xs, ws, rt=rt):
@@ -132,7 +132,7 @@ def moe_a2a_rows():
     import jax.numpy as jnp
     from functools import partial
     from jax.sharding import PartitionSpec as P
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     from repro.core import collectives
     from repro.core.communicator import Communicator
 
@@ -140,7 +140,7 @@ def moe_a2a_rows():
     if n < 2:
         return [("lmcoll_moe_a2a", 0.0, "skipped_1device")]
     dp = min(4, n)
-    mesh = jax.make_mesh((dp,), ("data",))
+    mesh = make_mesh((dp,), ("data",))
     comm = Communicator.from_mesh(mesh, "data")
     rng = np.random.RandomState(1)
     # (dp, cap, D) bucketed dispatch payload per device
@@ -150,7 +150,7 @@ def moe_a2a_rows():
     rows = []
     measured = {}
     for name, cc in (("fused", TP_FUSED), ("overlap", _overlap_cfg())):
-        @partial(compat.shard_map, mesh=mesh, in_specs=P("data"),
+        @partial(jax.shard_map, mesh=mesh, in_specs=P("data"),
                  out_specs=P("data"), check_vma=False)
         def f(v, cc=cc):
             return collectives.all_to_all(v, comm, cc, split_axis=0,

@@ -54,6 +54,8 @@ def _run_sweep(plan_dir: str, out_db: str, stats_path: str) -> float:
 
 def run():
     import jax
+    from repro.launch.mesh import exit_unless_host_cpu
+    exit_unless_host_cpu("benchmarks.plan_store")   # starts child sweeps
     if jax.device_count() < 8:
         return [("pstore", 0.0, "skipped_lt8devices")]
     with tempfile.TemporaryDirectory(prefix="repro-pstore-bench-") as td:

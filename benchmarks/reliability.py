@@ -32,13 +32,12 @@ import time
 def _time_permute(cfg, faults, x, mesh, perm, reps=30):
     import jax
     import numpy as np
-    from repro import compat
     from repro.core import reliable, streaming
 
     spec = jax.sharding.PartitionSpec("x")
     body = lambda v: streaming.chunked_permute(v[0], perm, "x", cfg)[None]
-    f = jax.jit(compat.shard_map(body, mesh=mesh, in_specs=spec,
-                                 out_specs=spec, check_vma=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=spec,
+                              out_specs=spec, check_vma=False))
     with reliable.inject(faults):
         jax.block_until_ready(f(x))          # trace bakes recovery rounds in
     best = float("inf")
@@ -54,13 +53,13 @@ def run():
     if jax.device_count() < 4:
         return [("rt", 0.0, "skipped_lt4devices")]
     import jax.numpy as jnp
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     from repro.core import reliable
     from repro.core.config import (CommConfig, CommMode, Reliability,
                                    Scheduling, Transport)
 
     n = 4
-    mesh = compat.make_mesh((n,), ("x",))
+    mesh = make_mesh((n,), ("x",))
     perm = [(i, (i + 1) % n) for i in range(n)]
     N = 16 * 256                              # 16 x 1 KiB wire chunks
     x = jnp.arange(n * N, dtype=jnp.float32).reshape(n, N) * 0.5 + 1.0

@@ -16,7 +16,7 @@ import numpy as np
 
 def run():
     import jax
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     import jax.numpy as jnp
     from functools import partial
     from jax.sharding import PartitionSpec as P
@@ -29,7 +29,7 @@ def run():
         return [("fig3", 0.0, "skipped_1device")]
 
     n = jax.device_count()
-    mesh = jax.make_mesh((n,), ("x",))
+    mesh = make_mesh((n,), ("x",))
     comm = Communicator.from_mesh(mesh, "x")
     builds = {
         "full_int8ring": CommConfig(algorithm="ring",
@@ -46,7 +46,7 @@ def run():
     x = jnp.zeros((n, 1 << 16), jnp.float32)
     rows = []
     for name, cfg in builds.items():
-        @partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
+        @partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
         def f(xs):
             return collectives.all_reduce(xs[0], comm, cfg)[None]
 

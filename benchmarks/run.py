@@ -6,7 +6,9 @@ perf trajectory is machine-trackable across PRs.
 
 Multi-device benches need >1 host device; when launched with a single CPU
 device this driver re-execs itself with 8 host devices (opt out with
-REPRO_BENCH_NO_REEXEC=1 or --single-device).
+REPRO_BENCH_NO_REEXEC=1 or --single-device).  Every row is a host-CPU
+rehearsal: on an accelerator the driver exits at once (``chip_smoke.py``
+is the chip path).
 """
 import json
 import os
@@ -29,6 +31,10 @@ def _ensure_devices():
 
 def main() -> None:
     _ensure_devices()
+    from repro.launch import compile_cache
+    from repro.launch.mesh import exit_unless_host_cpu
+    exit_unless_host_cpu("python -m benchmarks.run")
+    compile_cache.configure()
     from benchmarks import (b_eff, e2e_objective, fault_tolerance,
                             lm_collectives, lm_roofline, plan_store,
                             reliability, resources, serving, swe_scaling,

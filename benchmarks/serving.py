@@ -67,14 +67,14 @@ def _measure_db():
     """Measure every candidate under both phase consumers -> (db, named,
     per-phase {config key: e2e µs} tables)."""
     import jax
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     from repro.core.communicator import Communicator
     from repro.tune.db import TuneDB, TuneEntry, topology_key
     from repro.tune.space import config_to_dict
     from repro.tune import sweep as tune_sweep
 
     n = jax.device_count()
-    mesh = compat.make_mesh((n,), ("x",))
+    mesh = make_mesh((n,), ("x",))
     comm = Communicator.from_mesh(mesh, "x")
     topo = topology_key(mesh)
     mkey = tune_sweep._mesh_key(mesh)
@@ -148,10 +148,11 @@ def _child(db_path: str) -> None:
 
     from repro.configs.registry import get_smoke_config
     from repro.launch import input_specs as isp, setup
+    from repro.launch.mesh import make_mesh
     from repro.train import serve as serve_mod
 
     n = jax.device_count()
-    mesh = jax.make_mesh((n // 4, 4), ("data", "model"))
+    mesh = make_mesh((n // 4, 4), ("data", "model"))
     cfg = dataclasses.replace(get_smoke_config("qwen3-8b"),
                               dtype=jnp.float32)
     B, prompt, gen = n // 4, 8, CHILD_STEPS
@@ -188,6 +189,8 @@ def _child(db_path: str) -> None:
 
 def run():
     import jax
+    from repro.launch.mesh import exit_unless_host_cpu
+    exit_unless_host_cpu("benchmarks.serving")   # starts a 48-device child
     if jax.device_count() < 4:
         return [("srv", 0.0, "skipped_lt4devices")]
     db, named, e2e = _measure_db()

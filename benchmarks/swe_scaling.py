@@ -99,10 +99,11 @@ def fig11_overlap_predicted_vs_measured():
     if n < 2:
         return [("fig11_overlap", 0.0, "skipped_1device")]
     from repro.swe import driver
+    from repro.launch.mesh import make_mesh
     for parts in (2, 4, 8):
         if parts > n:
             break
-        dmesh = jax.make_mesh((parts,), ("data",))
+        dmesh = make_mesh((parts,), ("data",))
         measured = {}
         w = None
         for name, cfg in (("fused", ACCL_UDP), ("overlapped", ACCL_OVERLAP)):
@@ -137,10 +138,11 @@ def fig9_measured():
     if n < 2:
         return [("fig9_measured", 0.0, "skipped_1device")]
     from repro.swe import driver
+    from repro.launch.mesh import make_mesh
     for parts in (1, 2, 4, 8):
         if parts > n:
             break
-        dmesh = jax.make_mesh((parts,), ("data",))
+        dmesh = make_mesh((parts,), ("data",))
         sim = driver.build_simulation(600 * parts, dmesh, ACCL_UDP)
         run = driver.make_sim_runner(sim, n_inner=20)
         s = jax.block_until_ready(run(sim.state, 0.0))
@@ -162,7 +164,8 @@ def table1_resources():
     if jax.device_count() < 2:
         return [("table1", 0.0, "skipped_1device")]
     from repro.swe import driver
-    dmesh = jax.make_mesh((jax.device_count(),), ("data",))
+    from repro.launch.mesh import make_mesh
+    dmesh = make_mesh((jax.device_count(),), ("data",))
     for name, cfg in (("base", BASE), ("accl_udp", ACCL_UDP),
                       ("accl_tcp", ACCL_TCP), ("accl_overlap", ACCL_OVERLAP)):
         sim = driver.build_simulation(2000, dmesh, cfg)

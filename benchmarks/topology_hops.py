@@ -24,14 +24,14 @@ def run():
     import jax
     if jax.device_count() < 8:
         return [("topo_hops", 0.0, "skipped_lt8devices")]
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     from repro.core import latmodel
     from repro.core.config import OPTIMIZED_CONFIG, V5E
     from repro.core.topology import TorusSpec
     from repro.tune import sweep as tune_sweep
     from repro.tune.space import config_to_dict
 
-    mesh = compat.make_mesh((8,), ("x",))
+    mesh = make_mesh((8,), ("x",))
     spec = TorusSpec((2, 4))
     from repro.core.communicator import Communicator
     comm = Communicator.from_mesh(mesh, "x", topo=spec)

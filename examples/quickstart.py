@@ -10,7 +10,8 @@ compression, and the modeled latency difference (Eq. 1).
 import functools
 
 import jax
-from repro import compat
+from repro.launch import compile_cache
+from repro.launch.mesh import make_mesh
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
@@ -21,7 +22,7 @@ from repro.core import (CommConfig, CommMode, Compression, Communicator,
 
 def main():
     n = jax.device_count()
-    mesh = jax.make_mesh((n,), ("x",))
+    mesh = make_mesh((n,), ("x",))
     comm = Communicator.from_mesh(mesh, "x")
     print(f"mesh: {n} devices")
 
@@ -31,7 +32,7 @@ def main():
     for mode in (CommMode.STREAMING, CommMode.BUFFERED):
         cfg = CommConfig(mode=mode)
 
-        @functools.partial(compat.shard_map, mesh=mesh, in_specs=P("x"),
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("x"),
                            out_specs=P("x"))
         def ring(xs):
             return collectives.sendrecv(xs[0], comm.ring_perm(), comm, cfg)[None]
@@ -46,7 +47,7 @@ def main():
     for compression in (Compression.NONE, Compression.INT8):
         cfg = CommConfig(algorithm="ring", compression=compression)
 
-        @functools.partial(compat.shard_map, mesh=mesh, in_specs=P("x"),
+        @functools.partial(jax.shard_map, mesh=mesh, in_specs=P("x"),
                            out_specs=P("x"))
         def allreduce(xs):
             return collectives.all_reduce(xs[0], comm, cfg)[None]
@@ -65,4 +66,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.configure()
     main()

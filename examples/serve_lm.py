@@ -28,7 +28,8 @@ import numpy as np
 from repro.configs.registry import get_smoke_config
 from repro.core import plans
 from repro.core.config import CommConfig
-from repro.launch import input_specs as isp, setup
+from repro.launch import compile_cache, input_specs as isp, setup
+from repro.launch.mesh import make_mesh
 from repro.train import serve as serve_mod
 
 
@@ -88,7 +89,7 @@ def main():
     cfg = dataclasses.replace(get_smoke_config(args.arch), dtype=jnp.float32)
     n = jax.device_count()
     model_axis = 4 if n >= 4 else 1
-    mesh = jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    mesh = make_mesh((n // model_axis, model_axis), ("data", "model"))
     comm = "auto" if args.comm == "auto" else CommConfig()
     sess = setup.build_session(cfg, mesh, CommConfig(), concrete=True)
 
@@ -228,4 +229,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.configure()
     raise SystemExit(main())

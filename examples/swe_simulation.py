@@ -16,6 +16,8 @@ import numpy as np
 from repro.core import latmodel
 from repro.core.config import (BASELINE_CONFIG, OVERLAPPED_CONFIG, CommConfig,
                                V5E)
+from repro.launch import compile_cache
+from repro.launch.mesh import make_mesh
 from repro.obs import trace as obs_trace
 from repro.runtime.fault_tolerance import StepWatchdog
 from repro.swe import driver
@@ -46,7 +48,7 @@ def main():
                     help="persist CommPlans and compiled programs to this "
                          "directory (or set REPRO_PLAN_DIR): a rerun of the "
                          "same simulation starts warm — schedules replay "
-                         "from disk and XLA compiles come from the wired "
+                         "from disk and XLA compiles from JAX's "
                          "compilation cache")
     args = ap.parse_args()
 
@@ -59,7 +61,7 @@ def main():
               f"({store.entry_count()} entries on disk)")
 
     n = jax.device_count()
-    mesh = jax.make_mesh((n,), ("data",))
+    mesh = make_mesh((n,), ("data",))
     cfg = {"streaming": CommConfig(), "overlapped": OVERLAPPED_CONFIG,
            "baseline": BASELINE_CONFIG, "auto": "auto"}[args.comm]
     topology = None
@@ -87,17 +89,19 @@ def main():
     # instant in the trace and on the watchdog.stragglers counter.
     watchdog = StepWatchdog(warmup=2, window=16)
     t0 = time.perf_counter()
-    t = 20 * 1e-4
+    t = 20 * sim.swe.dt
     for i in range(args.steps // 20 - 1):
         watchdog.start_step(i)
         state = run(state, t)
         jax.block_until_ready(state)
         watchdog.end_step()
-        t += 20 * 1e-4
+        t += 20 * sim.swe.dt
     jax.block_until_ready(state)
     dt = (time.perf_counter() - t0) / max(args.steps - 20, 1)
     m1 = float(np.sum(np.asarray(state)[..., 0] * sim.pm.area * sim.pm.valid))
-    print(f"ran {args.steps} steps, {dt*1e6:.0f} us/step on CPU devices")
+    dev = jax.devices()[0]
+    print(f"ran {args.steps} steps, {dt*1e6:.0f} us/step on {n} x "
+          f"{dev.platform} {dev.device_kind}")
     print(f"mass conservation: {m0:.6f} -> {m1:.6f} "
           f"(drift {(m1-m0)/m0:.2e})")
     print(f"watchdog: median segment {watchdog.median_step*1e3:.1f}ms, "
@@ -126,4 +130,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.configure()
     main()

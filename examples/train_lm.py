@@ -18,7 +18,8 @@ import jax.numpy as jnp
 from repro.configs.registry import get_config, get_smoke_config
 from repro.core.config import CommConfig, OVERLAPPED_CONFIG
 from repro.data.pipeline import DataConfig
-from repro.launch import setup
+from repro.launch import compile_cache, setup
+from repro.launch.mesh import make_mesh
 from repro.optim import adamw
 from repro.train import loop as loop_mod
 
@@ -55,7 +56,7 @@ def main():
 
     n = jax.device_count()
     model_axis = 2 if n >= 4 else 1
-    mesh = jax.make_mesh((n // model_axis, model_axis), ("data", "model"))
+    mesh = make_mesh((n // model_axis, model_axis), ("data", "model"))
     print(f"arch={cfg.name} params≈{cfg.param_count()/1e6:.0f}M "
           f"mesh=({n//model_axis}x{model_axis})")
 
@@ -77,4 +78,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.configure()
     main()

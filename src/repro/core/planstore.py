@@ -19,11 +19,9 @@ entries keyed by the exact same value scheme the in-memory cache uses:
   via ``plans.jitted_program(..., example_args=...)``) are AOT-compiled and
   serialized whole (``jax.experimental.serialize_executable``) under
   ``<dir>/programs/`` — a fresh process deserializes and runs, paying
-  neither trace nor compile.  Everything else goes through **JAX's
-  persistent compilation cache**: activating a store points
-  ``jax_compilation_cache_dir`` at ``<dir>/xla-cache/`` (with the
-  min-size/min-time thresholds dropped so every program qualifies), so a
-  fresh process re-traces but replays the expensive XLA compile from disk.
+  neither trace nor compile.  Everything else goes through JAX's
+  persistent compilation cache, which the entry points place once at
+  start (:mod:`repro.launch.compile_cache`); this module never moves it.
 
 Durability contract:
 
@@ -63,7 +61,7 @@ ENV_VAR = "REPRO_PLAN_DIR"
 
 # plans._memo kinds whose values serialize to JSON and persist here.
 # "program" (compiled callables) is deliberately absent: it persists through
-# the JAX compilation cache wired by _wire_jax_cache instead.
+# serialized executables or JAX's compilation cache instead.
 DISK_KINDS = frozenset({"chunks", "rounds", "ring", "perm", "plan", "wire"})
 
 #: Sentinel returned by :meth:`PlanStore.get` when no usable entry exists
@@ -74,28 +72,24 @@ _LOCK = threading.RLock()
 _OVERRIDE: Optional[str] = None      # configure() override; None = env rules
 _EXPLICIT = False                    # configure() was called (even with "")
 _STORES: dict[str, "PlanStore"] = {}
-_WIRED_DIRS: set[str] = set()
 
 _DISK_STAT_NAMES = ("disk_hits", "disk_misses", "disk_writes", "disk_corrupt")
 _DISK_STATS = {k: obs_metrics.registry().counter(f"plans.{k}")
                for k in _DISK_STAT_NAMES}
 
 
-def configure(path: os.PathLike | str | None, wire_jax: bool = True
-              ) -> Optional[Path]:
+def configure(path: os.PathLike | str | None) -> Optional[Path]:
     """Explicitly set the store directory (CLI ``--plan-dir``).
 
     ``path=None`` clears the override so ``REPRO_PLAN_DIR`` governs again;
     ``path=""`` disables the store even when the env var is set.  Returns
-    the resolved directory (None when disabled).  ``wire_jax=False`` skips
-    pointing JAX's compilation cache at the store (unit tests that must not
-    mutate global jax config).
+    the resolved directory (None when disabled).
     """
     global _OVERRIDE, _EXPLICIT
     with _LOCK:
         _OVERRIDE = str(path) if path is not None else None
         _EXPLICIT = path is not None
-    store = active(wire_jax=wire_jax)
+    store = active()
     return store.root if store is not None else None
 
 
@@ -109,10 +103,9 @@ def plan_dir() -> Optional[Path]:
     return Path(env) if env else None
 
 
-def active(wire_jax: bool = True) -> Optional["PlanStore"]:
+def active() -> Optional["PlanStore"]:
     """The live :class:`PlanStore` for the configured directory, or None
-    when persistence is off.  First activation of a directory wires the JAX
-    persistent compilation cache into it (the traced-program half)."""
+    when persistence is off."""
     d = plan_dir()
     if d is None:
         return None
@@ -121,9 +114,6 @@ def active(wire_jax: bool = True) -> Optional["PlanStore"]:
         store = _STORES.get(key)
         if store is None:
             store = _STORES[key] = PlanStore(d)
-        if wire_jax and key not in _WIRED_DIRS:
-            _WIRED_DIRS.add(key)
-            _wire_jax_cache(d)
     return store
 
 
@@ -135,30 +125,6 @@ def disk_stats() -> dict:
 def reset_disk_stats() -> None:
     for c in _DISK_STATS.values():
         c.reset()
-
-
-def _wire_jax_cache(root: Path) -> None:
-    """Point JAX's persistent compilation cache at ``<root>/xla-cache`` so
-    traced programs (the sweep's jitted microbenchmarks, the driver's step
-    programs) skip XLA compilation in every later process.  Thresholds are
-    dropped to zero so the small host-CPU programs of the emulated runs
-    qualify.  Best-effort: an old jax without a knob just skips it."""
-    try:
-        import jax
-    except Exception:  # noqa: BLE001 — store stays usable for plan entries
-        return
-    cache_dir = root / "xla-cache"
-    try:
-        cache_dir.mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(cache_dir))
-    except Exception:  # noqa: BLE001
-        return
-    for name, value in (("jax_persistent_cache_min_compile_time_secs", 0.0),
-                        ("jax_persistent_cache_min_entry_size_bytes", -1)):
-        try:
-            jax.config.update(name, value)
-        except Exception:  # noqa: BLE001
-            pass
 
 
 # ----------------------------------------------------------------------
@@ -262,7 +228,7 @@ def _decode_value(kind: str, payload: Any) -> Any:
 # ----------------------------------------------------------------------
 
 class PlanStore:
-    """One plan directory: JSON plan entries + the XLA compilation cache.
+    """One plan directory: JSON plan entries + serialized executables.
 
     Thread-safe within a process (the module lock covers filesystem ops);
     cross-process safety comes from atomic replace-on-write — concurrent
@@ -422,8 +388,7 @@ class PlanStore:
             return 0
 
     def clear(self) -> None:
-        """Delete every plan and program entry (the XLA compilation cache
-        is left to jax)."""
+        """Delete every plan and program entry."""
         for pattern, root in (("*.json", self.plans_path),
                               ("*.pkl", self.programs_path)):
             try:

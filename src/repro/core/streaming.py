@@ -355,9 +355,9 @@ def overlapped_matmul_allreduce(h: jnp.ndarray, w: jnp.ndarray,
     form a two-deep ack chain — chunk *i*'s matmul waits on the delivery of
     reduce *i − 2*, the per-layer double buffering of the TP reduce — never
     on the whole history.  With ``n_chunks=1`` this degrades to the
-    buffered (sequential) pattern.  Bitwise-identical to the fused
-    matmul + all-reduce: row chunking and identity barriers never change
-    the arithmetic.
+    buffered (sequential) pattern.  Equal to the fused matmul + all-reduce
+    up to f32 rounding: the barriers change nothing, but XLA may sum a dot
+    over a block of rows in another order than over the whole matrix.
     """
     tokens = h.shape[0]
     if n_chunks is None:

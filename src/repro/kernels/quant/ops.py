@@ -1,4 +1,8 @@
-"""jit wrappers for the quantization kernels."""
+"""jit wrappers for the quantization kernels.
+
+The kernels compile for the TPU; ``interpret=True`` runs them in Pallas
+interpret mode on any backend (the CPU tests).
+"""
 import functools
 
 import jax
@@ -6,17 +10,11 @@ import jax
 from repro.kernels.quant.quant import quantize_pallas, dequantize_pallas
 
 
-def _on_tpu():
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def quantize(x, interpret=None):
-    interp = (not _on_tpu()) if interpret is None else interpret
-    return quantize_pallas(x, interpret=interp)
+def quantize(x, interpret: bool = False):
+    return quantize_pallas(x, interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("shape", "dtype", "interpret"))
-def dequantize(q, s, shape, dtype, interpret=None):
-    interp = (not _on_tpu()) if interpret is None else interpret
-    return dequantize_pallas(q, s, shape, dtype, interpret=interp)
+def dequantize(q, s, shape, dtype, interpret: bool = False):
+    return dequantize_pallas(q, s, shape, dtype, interpret=interpret)

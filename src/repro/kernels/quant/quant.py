@@ -23,11 +23,11 @@ def _quant_kernel(x_ref, q_ref, s_ref):
     scale = jnp.where(amax > 0, amax / 127.0, 1.0)
     q = jnp.clip(jnp.round(x / scale), -127, 127)
     q_ref[...] = q.astype(jnp.int8)
-    s_ref[0, 0] = scale
+    s_ref[...] = jnp.broadcast_to(scale, s_ref.shape)
 
 
 def _dequant_kernel(q_ref, s_ref, x_ref):
-    x_ref[...] = (q_ref[...].astype(jnp.float32) * s_ref[0, 0]
+    x_ref[...] = (q_ref[...].astype(jnp.float32) * s_ref[...]
                   ).astype(x_ref.dtype)
 
 
@@ -46,15 +46,15 @@ def quantize_pallas(x, interpret: bool = False):
         in_specs=[pl.BlockSpec((1, BLOCK_ROWS, LANES), lambda i: (i, 0, 0))],
         out_specs=[
             pl.BlockSpec((1, BLOCK_ROWS, LANES), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((nb, BLOCK_ROWS, LANES), jnp.int8),
-            jax.ShapeDtypeStruct((nb, 1), jnp.float32),
+            jax.ShapeDtypeStruct((nb, 1, 1), jnp.float32),
         ],
         interpret=interpret,
     )(tiles)
-    return q, s
+    return q, s[:, 0]
 
 
 def dequantize_pallas(q, s, shape, dtype, interpret: bool = False):
@@ -64,12 +64,12 @@ def dequantize_pallas(q, s, shape, dtype, interpret: bool = False):
         grid=(nb,),
         in_specs=[
             pl.BlockSpec((1, BLOCK_ROWS, LANES), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, 1), lambda i: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, BLOCK_ROWS, LANES), lambda i: (i, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nb, BLOCK_ROWS, LANES), dtype),
         interpret=interpret,
-    )(q, s)
+    )(q, s[:, :, None])
     n = 1
     for d in shape:
         n *= d

@@ -18,8 +18,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro import compat
-
 
 def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, hstate, *,
             chunk: int):
@@ -32,18 +30,26 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, hstate, *,
 
     x = x_ref[0].astype(jnp.float32)          # (L, P)
     dt = dt_ref[0].astype(jnp.float32)        # (L, 1)
-    a = a_ref[0, 0]                           # scalar A (negative)
     bmat = b_ref[0].astype(jnp.float32)       # (L, N)
     cmat = c_ref[0].astype(jnp.float32)       # (L, N)
 
-    da = dt[:, 0] * a                          # (L,)
-    cum = jnp.cumsum(da)                       # (L,)
-
-    # Intra-chunk: y_i += sum_{j<=i} exp(cum_i - cum_j) (C_i·B_j) dt_j x_j
-    diff = cum[:, None] - cum[None, :]
+    da = dt * a_ref[0]                         # (L, 1); A (1, 1) negative
+    # Inclusive prefix sum cum_i = sum_{j<=i} da_j as a lower-triangular
+    # matmul (Mosaic lowers no cumsum), once as a column, once as a row.
     mask = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0) >= \
         jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    lmat = jnp.exp(jnp.where(mask, diff, -jnp.inf))
+    tril = mask.astype(jnp.float32)
+    hi = jax.lax.Precision.HIGHEST
+    cum = jax.lax.dot_general(tril, da, (((1,), (0,)), ((), ())),
+                              precision=hi,
+                              preferred_element_type=jnp.float32)  # (L, 1)
+    cum_row = jax.lax.dot_general(da, tril, (((0,), (1,)), ((), ())),
+                                  precision=hi,
+                                  preferred_element_type=jnp.float32)  # (1, L)
+    cum_end = jnp.sum(da, axis=0, keepdims=True)                   # (1, 1)
+
+    # Intra-chunk: y_i += sum_{j<=i} exp(cum_i - cum_j) (C_i·B_j) dt_j x_j
+    lmat = jnp.exp(jnp.where(mask, cum - cum_row, -jnp.inf))
     cb = jax.lax.dot_general(cmat, bmat, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
     w = cb * lmat                              # (L, L)
@@ -52,17 +58,17 @@ def _kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, hout_ref, hstate, *,
                             preferred_element_type=jnp.float32)
 
     # Inter-chunk: y_i += C_i exp(cum_i) h_prev     h_prev: (N, P)
-    y = y + jax.lax.dot_general(cmat * jnp.exp(cum)[:, None], hstate[...],
+    y = y + jax.lax.dot_general(cmat * jnp.exp(cum), hstate[...],
                                 (((1,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)
 
     # Chunk state update: h = exp(cum_L) h_prev + sum_j exp(cum_L - cum_j)
     #                          dt_j B_j x_j^T
-    decay_end = jnp.exp(cum[-1] - cum)         # (L,)
-    s_c = jax.lax.dot_general(bmat * (decay_end * dt[:, 0])[:, None], x,
+    decay_end = jnp.exp(cum_end - cum)         # (L, 1)
+    s_c = jax.lax.dot_general(bmat * (decay_end * dt), x,
                               (((0,), (0,)), ((), ())),
                               preferred_element_type=jnp.float32)
-    hstate[...] = hstate[...] * jnp.exp(cum[-1]) + s_c
+    hstate[...] = hstate[...] * jnp.exp(cum_end) + s_c
 
     y_ref[0] = y.astype(y_ref.dtype)
 
@@ -89,7 +95,7 @@ def ssd_scan_pallas(x, dt, a, b, c, chunk: int, interpret: bool = False):
         in_specs=[
             pl.BlockSpec((1, chunk, p_dim), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, chunk, 1), lambda i, j: (i, j, 0)),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0)),
+            pl.BlockSpec((1, 1, 1), lambda i, j: (i, 0, 0)),
             pl.BlockSpec((1, chunk, n_dim), lambda i, j: (i, j, 0)),
             pl.BlockSpec((1, chunk, n_dim), lambda i, j: (i, j, 0)),
         ],
@@ -102,7 +108,7 @@ def ssd_scan_pallas(x, dt, a, b, c, chunk: int, interpret: bool = False):
             jax.ShapeDtypeStruct((bh, n_dim, p_dim), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((n_dim, p_dim), jnp.float32)],
-        compiler_params=compat.tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(x, dt[..., None], a[:, None], b, c)
+    )(x, dt[..., None], a[:, None, None], b, c)

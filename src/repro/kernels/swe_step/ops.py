@@ -1,4 +1,8 @@
-"""jit wrapper for the SWE element-update kernel."""
+"""jit wrapper for the SWE element-update kernel.
+
+The kernel compiles for the TPU; ``interpret=True`` runs it in Pallas
+interpret mode on any backend (the CPU tests).
+"""
 import functools
 
 import jax
@@ -6,13 +10,8 @@ import jax
 from repro.kernels.swe_step.swe_step import swe_step_pallas
 
 
-def _on_tpu():
-    return jax.default_backend() == "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("dt", "interpret"))
 def swe_step(u, u_n, nx, ny, edge_type, area, valid, h_sea, *, dt,
-             interpret=None):
-    interp = (not _on_tpu()) if interpret is None else interpret
+             interpret: bool = False):
     return swe_step_pallas(u, u_n, nx, ny, edge_type, area, valid, h_sea,
-                           dt=dt, interpret=interp)
+                           dt=dt, interpret=interpret)

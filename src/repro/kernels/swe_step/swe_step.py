@@ -73,10 +73,12 @@ def _flux_kernel(u_ref, un_ref, nx_ref, ny_ref, et_ref, area_ref, valid_ref,
         f2 = 0.5 * (fl[2] + fr[2] - lam * nlen * (u_r2 - u[:, 2]))
         div = div + jnp.stack([f0, f1, f2], axis=-1)
 
-    area = area_ref[...].astype(jnp.float32)[:, None]
-    valid = valid_ref[...].astype(jnp.float32)[:, None]
+    area = area_ref[...].astype(jnp.float32)      # (T,1)
+    valid = valid_ref[...].astype(jnp.float32)    # (T,1)
     new = (u - dt / jnp.maximum(area, 1e-12) * div) * valid
-    new = new.at[:, 0].set(jnp.maximum(new[:, 0], 1e-6) * valid[:, 0])
+    # depth floor on column 0 (h); a select, since Mosaic has no scatter
+    col = jax.lax.broadcasted_iota(jnp.int32, new.shape, 1)
+    new = jnp.where(col == 0, jnp.maximum(new, 1e-6) * valid, new)
     out_ref[...] = new.astype(out_ref.dtype)
 
 
@@ -101,13 +103,13 @@ def swe_step_pallas(u, u_n, nx, ny, edge_type, area, valid, h_sea, *,
             pl.BlockSpec((TILE_E, 3), lambda i: (i, 0)),
             pl.BlockSpec((TILE_E, 3), lambda i: (i, 0)),
             pl.BlockSpec((TILE_E, 3), lambda i: (i, 0)),
-            pl.BlockSpec((TILE_E,), lambda i: (i,)),
-            pl.BlockSpec((TILE_E,), lambda i: (i,)),
+            pl.BlockSpec((TILE_E, 1), lambda i: (i, 0)),
+            pl.BlockSpec((TILE_E, 1), lambda i: (i, 0)),
             pl.BlockSpec((1, 1), lambda i: (0, 0)),
         ],
         out_specs=pl.BlockSpec((TILE_E, 3), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((u.shape[0], 3), u.dtype),
         interpret=interpret,
-    )(u, u_n, nx, ny, edge_type, area, valid,
+    )(u, u_n, nx, ny, edge_type, area[:, None], valid[:, None],
       jnp.asarray(h_sea, jnp.float32)[None, None])
     return out[:E]

@@ -1,7 +1,3 @@
-import os
-os.environ["XLA_FLAGS"] = (os.environ.get("DRYRUN_BASE_XLA_FLAGS", "")
-                           + " --xla_force_host_platform_device_count=512").strip()
-
 """Multi-pod dry-run: lower + compile every (arch × shape × mesh) cell.
 
 For each cell this driver
@@ -16,10 +12,13 @@ into ``artifacts/dryrun/{arch}__{shape}__{mesh}.json`` for EXPERIMENTS.md.
 Usage:
     python -m repro.launch.dryrun --arch qwen3-8b --shape train_4k
     python -m repro.launch.dryrun --all [--multi-pod] [--shapes train_4k,...]
+
+It runs on 512 forced host CPU devices and exits at once on an accelerator.
 """
 import argparse
 import dataclasses
 import json
+import os
 import re
 import time
 import traceback
@@ -29,8 +28,8 @@ import jax
 
 from repro.configs.registry import get_config, list_archs
 from repro.core.config import CommConfig, CommMode, Scheduling, Transport, Compression
-from repro.launch import input_specs as isp
-from repro.launch.mesh import make_production_mesh
+from repro.launch import compile_cache, input_specs as isp
+from repro.launch.mesh import exit_unless_host_cpu, make_production_mesh
 
 ARTIFACTS = Path(__file__).resolve().parents[3] / "artifacts" / "dryrun"
 
@@ -296,6 +295,12 @@ def main():
     ap.add_argument("--remat-policy", default="")
     ap.add_argument("--tag", default="")
     args = ap.parse_args()
+    # Read when the CPU backend starts, which the check below does.
+    os.environ["XLA_FLAGS"] = (
+        os.environ.get("DRYRUN_BASE_XLA_FLAGS", "")
+        + " --xla_force_host_platform_device_count=512").strip()
+    exit_unless_host_cpu("python -m repro.launch.dryrun")
+    compile_cache.configure()
 
     archs = list_archs() if (args.all or not args.arch) else [args.arch]
     shapes = (args.shapes.split(",") if args.shapes

@@ -27,6 +27,7 @@ _DTYPE_BYTES = {"f64": 8, "f32": 4, "f16": 2, "bf16": 2, "s64": 8, "u64": 8,
                 "pred": 1, "c64": 8, "c128": 16, "f8e4m3fn": 1, "f8e5m2": 1}
 
 _TYPE_RE = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
+_COMMENT_RE = re.compile(r"/\*.*?\*/")
 _COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                 "collective-permute")
 _SKIP_OPS = ("parameter", "constant", "tuple", "get-tuple-element", "bitcast",
@@ -50,13 +51,17 @@ def _shape_elems(dims: str) -> int:
 
 
 def split_computations(text: str):
-    """name -> list of op lines; also returns entry name."""
+    """name -> list of op lines, ``/*...*/`` comments stripped (tuple types
+    carry ``/*index=5*/`` markers); also returns entry name.  Reads both the
+    optimized text (``%name (params) -> type {`` headers) and the lowered,
+    pre-optimization text (``name {``, ``ENTRY name {``)."""
     comps = {}
     entry = None
     cur = None
     for line in text.splitlines():
-        s = line.strip()
-        m = re.match(r"(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\)\s*->\s*.+\{\s*$", s)
+        s = _COMMENT_RE.sub("", line).strip()
+        m = re.match(r"(ENTRY\s+)?%?([\w.\-]+)\s*(?:\(.*\)\s*->\s*.+)?\{\s*$",
+                     s)
         if m and not s.startswith("ROOT"):
             cur = m.group(2)
             comps[cur] = []
@@ -85,6 +90,9 @@ def build_symtab(comps) -> dict:
     return sym
 
 
+_OPERAND_RE = re.compile(r"(?:^|\s)%?([A-Za-z_][\w.\-]*)\s*$")
+
+
 def _operand_names(line: str):
     rhs = line.split("=", 1)[1]
     if "(" not in rhs:
@@ -101,7 +109,20 @@ def _operand_names(line: str):
             if depth == 0:
                 end = i
                 break
-    return re.findall(r"%([\w.\-]+)", call[:end])
+    # operands are comma-separated at bracket depth 0; each ends in its name,
+    # with a ``%`` in optimized text and without one in lowered text
+    names, depth, start = [], 0, 1
+    for i, ch in enumerate(call[:end + 1]):
+        if ch in "([{":
+            depth += 1
+        elif ch in ")]}":
+            depth -= 1
+        if (ch == "," and depth == 1) or i == end:
+            m = _OPERAND_RE.search(call[start:i])
+            if m:
+                names.append(m.group(1))
+            start = i + 1
+    return names
 
 
 def _called(line: str):

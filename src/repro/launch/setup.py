@@ -13,7 +13,6 @@ import functools
 from typing import Any, Optional
 
 import jax
-from repro import compat
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -45,7 +44,11 @@ def build_session(cfg: ModelConfig, mesh: Mesh, comm: CommConfig | str,
                   seq_parallel: bool = False,
                   tune_db_path=None,
                   objective: str = "latency") -> Session:
-    """Build a training session.
+    """Build a session: mesh, runtime, specs and (``concrete``) params.
+
+    The optimizer state is left to the trainers (:func:`init_opt_state`):
+    serving a model never reads its Adam moments, which in f32 take four
+    times the bytes of bf16 weights.
 
     ``comm="auto"`` asks the autotuner for the fastest measured config for
     the LM path's dominant collective — the per-layer row-parallel TP
@@ -83,10 +86,7 @@ def build_session(cfg: ModelConfig, mesh: Mesh, comm: CommConfig | str,
     sess.ms_mask = sharding.model_sharded_mask(pspec)
     if concrete:
         out_shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), pspec)
-        with jax.default_device(jax.devices()[0]):
-            pass
         sess.params = jax.jit(init_fn, out_shardings=out_shardings)(key)
-        sess.opt_state = init_opt_state(sess)
     return sess
 
 
@@ -99,7 +99,7 @@ def init_opt_state(sess: Session):
     def _init(params):
         return adamw.init_state(params, sess.oc, rt, rt.fsdp_plan)
 
-    fn = jax.jit(compat.shard_map(
+    fn = jax.jit(jax.shard_map(
         _init, mesh=mesh, in_specs=(sess.param_spec,),
         out_specs=sess.opt_spec, check_vma=False))
     return fn(sess.params)
@@ -127,7 +127,7 @@ def make_sharded_train_step(sess: Session, accum_steps: int = 1,
         {"tokens": 0, "labels": 0})
 
     def build(batch_tree_spec):
-        sm = compat.shard_map(
+        sm = jax.shard_map(
             wrapped, mesh=sess.mesh,
             in_specs=(sess.param_spec, sess.opt_spec, batch_tree_spec),
             out_specs=(sess.param_spec, sess.opt_spec, metric_spec),
@@ -143,7 +143,7 @@ def make_sharded_eval_step(sess: Session):
     metric_spec = {"loss": P(), "ce": P(), "aux": P()}
 
     def build(batch_tree_spec):
-        sm = compat.shard_map(
+        sm = jax.shard_map(
             fn, mesh=sess.mesh,
             in_specs=(sess.param_spec, batch_tree_spec),
             out_specs=metric_spec,

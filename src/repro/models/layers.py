@@ -219,7 +219,8 @@ def row_parallel(x_shard: jnp.ndarray, w_shard: jnp.ndarray, rt: Runtime) -> jnp
     per-layer TP reduce is chunked and double-buffered against the matmul,
     reusing the runtime's TP communicator so hop-aware tuning sees the real
     topology.  Buffered+fused issues one psum after the full matmul (paper
-    §3.1/§5 applied to TP).  All paths are bitwise-identical.
+    §3.1/§5 applied to TP).  The chunked path agrees with the fused one to
+    f32 rounding: a dot over a block of rows may sum in another order.
     """
     if rt.mesh.tp == 1:
         return jnp.dot(x_shard, w_shard, preferred_element_type=jnp.float32

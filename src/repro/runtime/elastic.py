@@ -91,8 +91,8 @@ def _sim_configs(sim) -> list:
 
 def _survivor_mesh(n: int):
     import jax
-    from jax.sharding import Mesh
-    return Mesh(np.array(jax.devices()[:n]), ("data",))
+    from repro.launch.mesh import make_mesh
+    return make_mesh((n,), ("data",), devices=jax.devices()[:n])
 
 
 def _physical_edges(spec) -> list:
@@ -412,6 +412,10 @@ def main(argv=None) -> int:
     os.environ.setdefault(
         "XLA_FLAGS",
         f"--xla_force_host_platform_device_count={args.devices}")
+    from repro.launch import compile_cache
+    from repro.launch.mesh import exit_unless_host_cpu
+    exit_unless_host_cpu("python -m repro.runtime.elastic")
+    compile_cache.configure()
 
     from repro.core.topology import TorusSpec
     topology = TorusSpec.parse(args.topology) if args.topology else None

@@ -285,13 +285,12 @@ def resume_session(ckpt_dir, sess, step: Optional[int] = None):
                              sess.param_spec)
     sess.params = ck.restore(step, sess.params, target_sharding=shardings)
     opt_ck = Checkpointer(Path(ckpt_dir) / "opt")
+    sess.opt_state = setup.init_opt_state(sess)
     if opt_ck.latest_step() == step:
         opt_shardings = jax.tree.map(
             lambda s: NamedSharding(sess.mesh, s), sess.opt_spec)
         sess.opt_state = opt_ck.restore(step, sess.opt_state,
                                         target_sharding=opt_shardings)
-    else:
-        sess.opt_state = setup.init_opt_state(sess)
     sess.opt_state["step"] = jax.device_put(
         jnp.asarray(step, jnp.int32),
         NamedSharding(sess.mesh, jax.sharding.PartitionSpec()))

@@ -22,7 +22,6 @@ from functools import partial
 from typing import Optional
 
 import jax
-from repro import compat
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -137,8 +136,12 @@ def build_simulation(n_elements: int, device_mesh: Mesh,
     ``initial_state`` (global ``(E, 3)``, e.g. from :func:`flatten_state`)
     seeds the partitions with a mid-run snapshot instead of the t=0 hump —
     the elastic-recovery path restoring onto a different partition count.
+
+    ``swe.dt`` is shortened to what the mesh keeps stable
+    (:func:`dg_solver.stable_dt`).
     """
     mesh = generate_bight_mesh(n_elements, seed=seed)
+    swe = dataclasses.replace(swe, dt=dg_solver.stable_dt(mesh, swe))
     n_parts = device_mesh.shape["data"]
     if initial_state is None:
         initial_state = dg_solver.initial_state(mesh)
@@ -217,7 +220,7 @@ def make_sim_runner(sim: Simulation, n_inner: int = 10):
         (state, t), _ = jax.lax.scan(inner, (state, t0), jnp.arange(n_inner))
         return state
 
-    sm = compat.shard_map(body, mesh=sim.device_mesh,
+    sm = jax.shard_map(body, mesh=sim.device_mesh,
                        in_specs=in_specs, out_specs=P("data"),
                        check_vma=False)
     fn = jax.jit(sm)
@@ -249,7 +252,7 @@ def make_host_scheduled_runner(sim: Simulation):
         payloads = state[:, send_idx[0]] * send_mask[0][None, ..., None]
         return payloads   # (1, R, S, 3) on this device
 
-    gather_sm = jax.jit(compat.shard_map(
+    gather_sm = jax.jit(jax.shard_map(
         gather, mesh=sim.device_mesh,
         in_specs=(P("data"), P("data"), P("data")), out_specs=P("data"),
         check_vma=False))
@@ -263,7 +266,7 @@ def make_host_scheduled_runner(sim: Simulation):
         return s
 
     in_specs = (P("data"),) + (P("data"),) * len(arg_list) + (P(),)
-    step_sm = jax.jit(compat.shard_map(
+    step_sm = jax.jit(jax.shard_map(
         phase2, mesh=sim.device_mesh, in_specs=in_specs, out_specs=P("data"),
         check_vma=False))
 

@@ -62,6 +62,8 @@ def train(sess: setup_mod.Session, data_cfg: DataConfig, loop: LoopConfig,
     log(f"[comm] mode={cc.mode.value} scheduling={cc.scheduling.value} "
         f"transport={cc.transport.value} algorithm={cc.algorithm}")
 
+    if sess.opt_state is None:
+        sess.opt_state = setup_mod.init_opt_state(sess)
     source = SyntheticLM(data_cfg)
     start_step = int(np.asarray(jax.device_get(sess.opt_state["step"])))
     loader = PrefetchLoader(source, start_step=start_step)

@@ -16,7 +16,6 @@ import functools
 from typing import Any
 
 import jax
-from repro import compat
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
@@ -127,8 +126,8 @@ def build_serve_fn(cfg: ModelConfig, mesh, comm,
         def fn(params, batch):
             return dec.prefill(params, batch, rt, max_len)
 
-        sm = compat.shard_map(fn, mesh=mesh, in_specs=(pspec, bspec),
-                              out_specs=state_spec, check_vma=False)
+        sm = jax.shard_map(fn, mesh=mesh, in_specs=(pspec, bspec),
+                           out_specs=state_spec, check_vma=False)
         return rt, jax.jit(sm), (abstract_params, batch)
 
     # decode
@@ -139,7 +138,7 @@ def build_serve_fn(cfg: ModelConfig, mesh, comm,
     def fn(params, token, state):
         return dec.decode_step(params, token, state, rt)
 
-    sm = compat.shard_map(fn, mesh=mesh,
-                          in_specs=(pspec, token_spec, state_spec),
-                          out_specs=state_spec, check_vma=False)
+    sm = jax.shard_map(fn, mesh=mesh,
+                       in_specs=(pspec, token_spec, state_spec),
+                       out_specs=state_spec, check_vma=False)
     return rt, jax.jit(sm), (abstract_params, token, state_abs)

@@ -397,7 +397,6 @@ def _time_program(op: Callable, mesh, msg_bytes: int, cfg: CommConfig,
     import jax
     from jax import numpy as jnp
     from jax.sharding import PartitionSpec as P
-    from repro import compat
 
     # Shard dim 0 jointly over every mesh axis (the hierarchical all-reduce
     # benches on a 2-axis inner×outer mesh; everything else on one axis).
@@ -412,7 +411,7 @@ def _time_program(op: Callable, mesh, msg_bytes: int, cfg: CommConfig,
                        jax.sharding.NamedSharding(mesh, spec))
 
     def build_single():
-        return jax.jit(compat.shard_map(
+        return jax.jit(jax.shard_map(
             lambda xs: op(xs[0])[None], mesh=mesh,
             in_specs=spec, out_specs=spec, check_vma=False))
 
@@ -422,7 +421,7 @@ def _time_program(op: Callable, mesh, msg_bytes: int, cfg: CommConfig,
         def build_many():
             def many(xs):
                 for _ in range(inner):
-                    xs = compat.shard_map(
+                    xs = jax.shard_map(
                         lambda v: op(v[0])[None], mesh=mesh,
                         in_specs=spec, out_specs=spec, check_vma=False)(xs)
                 return xs
@@ -557,7 +556,7 @@ def run_sweep(mesh=None, collectives: Sequence[str] = SWEEPABLE,
     selection pipeline testable end-to-end without wall-clock noise.
     """
     import jax
-    from repro import compat
+    from repro.launch.mesh import make_mesh
     from repro.core.communicator import Communicator
 
     if objective not in OBJECTIVES:
@@ -572,7 +571,7 @@ def run_sweep(mesh=None, collectives: Sequence[str] = SWEEPABLE,
             if loss_rate > 0.0 else None)
     losskey: tuple = (("loss", loss_rate),) if wire is not None else ()
     if mesh is None:
-        mesh = compat.make_mesh((jax.device_count(),), ("x",))
+        mesh = make_mesh((jax.device_count(),), ("x",))
     if sizes is None:
         sizes = FAST_SIZES if fast else FULL_SIZES
     if db is None:
@@ -639,7 +638,7 @@ def run_sweep(mesh=None, collectives: Sequence[str] = SWEEPABLE,
                     f">= 4, have {n})")
                 continue
             # inner (in-pod / ICI) × outer (cross-pod / DCN) factorization
-            bench_mesh = compat.make_mesh((n // 2, 2), ("inner", "outer"))
+            bench_mesh = make_mesh((n // 2, 2), ("inner", "outer"))
             inner_comm = Communicator.from_mesh(bench_mesh, "inner")
             outer_comm = Communicator.from_mesh(bench_mesh, "outer")
             subcomms = (inner_comm, outer_comm)
@@ -828,7 +827,10 @@ def sweep_summary(stats: dict) -> str:
 # ----------------------------------------------------------------------
 
 def _ensure_devices(n: int) -> None:
-    """Re-exec with N host CPU devices when launched on a single device."""
+    """Re-exec with N host CPU devices when launched on a single device.
+
+    The flag only sizes the CPU backend: on an accelerator the sweep runs in
+    this one process on the accelerator's devices."""
     if os.environ.get("REPRO_TUNE_NO_REEXEC"):
         return
     flags = os.environ.get("XLA_FLAGS", "")
@@ -987,6 +989,10 @@ def main(argv: Sequence[str] | None = None) -> int:
     if store is not None:
         print(f"plan store: {store.root} "
               f"({store.entry_count()} entries on disk)")
+    if args.warm_check and store is not None and jax.default_backend() != "cpu":
+        ap.error(f"--warm-check with a plan store reruns the sweep in a "
+                 f"child process, which cannot reach the {jax.default_backend()}"
+                 f" this process holds; run it with JAX_PLATFORMS=cpu")
 
     if args.sizes in NAMED_SIZES:
         sizes = NAMED_SIZES[args.sizes]
@@ -1080,4 +1086,6 @@ def main(argv: Sequence[str] | None = None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch import compile_cache
+    compile_cache.configure()
     raise SystemExit(main())

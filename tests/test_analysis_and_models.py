@@ -38,14 +38,14 @@ def test_hlo_analysis_scan_trip_counts():
 def test_hlo_analysis_counts_collectives_in_scans():
     out = run_multidevice("""
 import jax, jax.numpy as jnp
-from repro import compat
+from repro.launch.mesh import make_mesh
 from functools import partial
 from jax.sharding import PartitionSpec as P
 from repro.launch.hlo_analysis import analyze_hlo
 
-mesh = jax.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 
-@partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+@partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
          check_vma=False)
 def f(xs):
     def body(c, _):
@@ -61,6 +61,35 @@ assert a["collective_bytes"]["all-reduce"] == 7 * 1024 * 4
 print("HLO COLLECTIVES OK")
 """)
     assert "HLO COLLECTIVES OK" in out
+
+
+@pytest.mark.parametrize("lowered", (False, True),
+                         ids=("optimized", "lowered"))
+def test_hlo_analysis_parses_tuple_and_async_collectives(lowered):
+    """Combined collectives print a tuple type with ``/*index=5*/`` markers;
+    TPU programs split them into ``-start``/``-done`` pairs.  The lowered,
+    pre-optimization text prints ``ENTRY main {`` and names without ``%``."""
+    from repro.launch.hlo_analysis import permute_overlap_stats
+    tup = ", ".join(("/*index=5*/" if i == 5 else "") + "f32[8,32]{1,0}"
+                    for i in range(6))
+    ops = ", ".join(f"%d{i}" for i in range(6))
+    text = "\n".join([
+        "ENTRY %main (p: f32[8,32]) -> f32[8,32] {",
+        *(f"  %d{i} = f32[8,32]{{1,0}} add(%p, %p)" for i in range(6)),
+        f"  %ar = ({tup}) all-reduce({ops}), channel_id=1, to_apply=%sum",
+        "  %s0 = f32[8,32]{1,0} all-reduce-start(%d0), channel_id=2",
+        "  %s1 = f32[8,32]{1,0} all-reduce-start(%d1), channel_id=3",
+        "  %m = f32[8,32]{1,0} multiply(%p, %p)",
+        "  %e0 = f32[8,32]{1,0} all-reduce-done(%s0)",
+        "  ROOT %e1 = f32[8,32]{1,0} all-reduce-done(%s1)",
+        "}"])
+    if lowered:
+        text = text.replace("%", "").replace(
+            "main (p: f32[8,32]) -> f32[8,32]", "main")
+    st = permute_overlap_stats(text, ops=("all-reduce",))
+    assert st["n_collectives"] == 3, st
+    assert st["sync_permutes"] == 1 and st["async_pairs"] == 2, st
+    assert st["pair_gaps"] == [1, 1] and st["independent_pairs"] == 3, st
 
 
 def test_latency_model_eq1_properties():
@@ -94,7 +123,6 @@ def test_scheduler_runners_equivalent():
     """Host-scheduled and fused runners must produce identical numerics; the
     host runner pays one dispatch per phase (the paper's l_k accounting)."""
     import jax.numpy as jnp
-    from repro import compat
     from repro.core import scheduler
 
     phases = [
@@ -116,17 +144,17 @@ def test_scheduler_runners_equivalent():
 def test_streaming_pipelined_consume():
     out = run_multidevice("""
 import jax, numpy as np, jax.numpy as jnp
-from repro import compat
+from repro.launch.mesh import make_mesh
 from functools import partial
 from jax.sharding import PartitionSpec as P
 from repro.core import CommConfig, Communicator, streaming
 
-mesh = jax.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 comm = Communicator.from_mesh(mesh, "x")
 cfg = CommConfig(chunk_bytes=512)
 x = np.random.RandomState(0).randn(8, 256).astype(np.float32)
 
-@partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=(P("x"), P("x")))
+@partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=(P("x"), P("x")))
 def f(xs):
     total, received = streaming.pipelined_consume(
         xs[0], comm.ring_perm(), "x", cfg,

@@ -9,12 +9,12 @@ from helpers import run_multidevice
 def test_all_reduce_all_algorithms():
     out = run_multidevice("""
 import jax, numpy as np, jax.numpy as jnp
-from repro import compat
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from functools import partial
 from repro.core import (CommConfig, Compression, Communicator, collectives)
 
-mesh = jax.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 comm = Communicator.from_mesh(mesh, "x")
 x = np.random.RandomState(0).randn(8, 40).astype(np.float32)
 ref = x.sum(0)
@@ -24,7 +24,7 @@ for name, cfg, tol in [
     ("ring_int8", CommConfig(algorithm="ring", compression=Compression.INT8), 2e-1),
     ("ring_bf16", CommConfig(algorithm="ring", compression=Compression.BF16), 1e-1),
 ]:
-    @partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
     def f(xs):
         return collectives.all_reduce(xs[0], comm, cfg)[None]
     out = np.asarray(f(x))
@@ -38,19 +38,19 @@ print("OK")
 def test_sendrecv_modes_and_transports():
     out = run_multidevice("""
 import jax, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from functools import partial
 from repro.core import CommConfig, CommMode, Transport, Communicator, collectives
 
-mesh = jax.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 comm = Communicator.from_mesh(mesh, "x")
 x = np.random.RandomState(1).randn(8, 130).astype(np.float32)
 for mode in (CommMode.STREAMING, CommMode.BUFFERED):
     for tr in (Transport.ORDERED, Transport.UNORDERED):
         for chunk in (512, 2048):
             cfg = CommConfig(mode=mode, transport=tr, chunk_bytes=chunk, window=2)
-            @partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
+            @partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
             def g(xs):
                 return collectives.sendrecv(xs[0], comm.ring_perm(), comm, cfg)[None]
             out = np.asarray(g(x))
@@ -63,17 +63,17 @@ print("OK")
 def test_reduce_scatter_and_gather_roundtrip():
     out = run_multidevice("""
 import jax, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from functools import partial
 from repro.core import CommConfig, Communicator, collectives
 
-mesh = jax.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 comm = Communicator.from_mesh(mesh, "x")
 x = np.random.RandomState(2).randn(8, 16, 5).astype(np.float32)
 for algo in ("native", "ring"):
     cfg = CommConfig(algorithm=algo)
-    @partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
     def rs(xs):
         seg = collectives.reduce_scatter(xs[0], comm, cfg)
         return collectives.all_gather(seg, comm, cfg, axis=0)[None]
@@ -88,16 +88,16 @@ print("OK")
 def test_hierarchical_all_reduce_multipod():
     out = run_multidevice("""
 import jax, numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from functools import partial
 from repro.core import CommConfig, Communicator, collectives
 
-mesh = jax.make_mesh((2, 4), ("pod", "data"))
+mesh = make_mesh((2, 4), ("pod", "data"))
 ci = Communicator.from_mesh(mesh, "data")
 co = Communicator.from_mesh(mesh, "pod")
 x = np.random.RandomState(3).randn(2, 4, 33).astype(np.float32)
-@partial(compat.shard_map, mesh=mesh, in_specs=P("pod", "data"),
+@partial(jax.shard_map, mesh=mesh, in_specs=P("pod", "data"),
          out_specs=P("pod", "data"))
 def f(xs):
     return collectives.hierarchical_all_reduce(

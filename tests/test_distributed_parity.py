@@ -23,7 +23,7 @@ GRAD_TOL = {  # relative, per max|grad| of the leaf
 _TEMPLATE = """
 import dataclasses
 import jax, numpy as np, jax.numpy as jnp
-from repro import compat
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro.configs.registry import get_smoke_config
 from repro.core.config import CommConfig
@@ -61,7 +61,7 @@ def grads_for(mesh, fsdp=False):
         return loss, grads
     bspec = jax.tree.map(
         lambda _: P(tuple(a for a in mesh.axis_names if a != "model")), batch)
-    sm = jax.jit(compat.shard_map(f, mesh=mesh, in_specs=(sess.param_spec, bspec),
+    sm = jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(sess.param_spec, bspec),
                                out_specs=(P(), sess.param_spec),
                                check_vma=False))
     loss, grads = sm(sess.params, batch)
@@ -74,8 +74,8 @@ def trim(a, b):
     sl = tuple(slice(0, min(x, y)) for x, y in zip(a.shape, b.shape))
     return a[sl], b[sl]
 
-l1, g1 = grads_for(jax.make_mesh((1, 1), ("data", "model")))
-l4, g4 = grads_for(jax.make_mesh((1, 4), ("data", "model")))
+l1, g1 = grads_for(make_mesh((1, 1), ("data", "model")))
+l4, g4 = grads_for(make_mesh((1, 4), ("data", "model")))
 assert abs(l1 - l4) < 1e-4, ("loss fwd parity", l1, l4)
 flat1, _ = jax.tree_util.tree_flatten_with_path(g1)
 flat4 = jax.tree.leaves(g4)
@@ -104,7 +104,7 @@ def test_train_steps_parity_dense(arch):
     out = run_multidevice("""
 import dataclasses
 import jax, numpy as np, jax.numpy as jnp
-from repro import compat
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro.configs.registry import get_smoke_config
 from repro.core.config import CommConfig
@@ -124,12 +124,12 @@ def run(mesh, fsdp=False, steps=3):
     bspec = jax.tree.map(
         lambda _: P(tuple(a for a in mesh.axis_names if a != "model")), batch)
     step = setup.make_sharded_train_step(sess, donate=False)(bspec)
-    p, o = sess.params, sess.opt_state
+    p, o = sess.params, setup.init_opt_state(sess)
     for i in range(steps):
         p, o, m = step(p, o, batch)
     return jax.tree.map(lambda x: np.asarray(jax.device_get(x)), p), m
 
-ref, mref = run(jax.make_mesh((1, 1), ("data", "model")))
+ref, mref = run(make_mesh((1, 1), ("data", "model")))
 for fsdp in (False, True):
     got, mgot = run(meshlib.make_test_mesh(data=2, model=4), fsdp=fsdp)
     assert abs(float(mref["loss"]) - float(mgot["loss"])) < 5e-4, \
@@ -146,7 +146,7 @@ def test_multipod_mesh_train_runs():
     out = run_multidevice("""
 import dataclasses
 import jax, numpy as np, jax.numpy as jnp
-from repro import compat
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro.configs.registry import get_smoke_config
 from repro.core.config import CommConfig
@@ -154,7 +154,7 @@ from repro.launch import setup
 from repro.optim import adamw
 
 cfg = dataclasses.replace(get_smoke_config("qwen3-8b"), dtype=jnp.float32)
-mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
 oc = adamw.OptConfig(lr=1e-3, zero1=True)
 sess = setup.build_session(cfg, mesh, CommConfig(), oc=oc)
 rng = np.random.RandomState(0)
@@ -162,7 +162,7 @@ batch = {"tokens": jnp.asarray(rng.randint(0, cfg.vocab_size, (4, 32))),
          "labels": jnp.asarray(rng.randint(0, cfg.vocab_size, (4, 32)))}
 bspec = jax.tree.map(lambda _: P(("pod", "data")), batch)
 step = setup.make_sharded_train_step(sess, donate=False)(bspec)
-p, o, m = step(sess.params, sess.opt_state, batch)
+p, o, m = step(sess.params, setup.init_opt_state(sess), batch)
 assert np.isfinite(float(m["loss"]))
 print("MULTIPOD OK", float(m["loss"]))
 """)

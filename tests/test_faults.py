@@ -740,6 +740,7 @@ def test_lm_rank_loss_elastic_reselect(tmp_path):
     out = run_multidevice(_SPLIT_DB_SNIPPET + f"""
 import dataclasses, shutil
 import jax, numpy as np, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.configs.registry import get_smoke_config
 from repro.core.config import CommConfig, CommMode
 from repro.core.topology import TorusSpec
@@ -762,7 +763,7 @@ reg = obs_metrics.registry()
 sweeps0 = reg.counter("sweep.runs").value
 
 def faulted_run(ckpt_dir):
-    mesh = jax.make_mesh((8, 1), ("data", "model"))
+    mesh = make_mesh((8, 1), ("data", "model"))
     sess = setup.build_session(cfg, mesh, comm, oc=oc)
     inj = FaultInjector(FaultSchedule.parse("rank_lost@3=r7"))
     losses = []
@@ -778,7 +779,7 @@ def faulted_run(ckpt_dir):
     from repro.checkpoint.checkpointer import Checkpointer
     assert Checkpointer(ckpt_dir).latest_step() == 3
     # survivors: 4 devices; recovery re-selects from the model, not a sweep
-    mesh2 = jax.make_mesh((4, 1), ("data", "model"))
+    mesh2 = make_mesh((4, 1), ("data", "model"))
     sess2, start = elastic_restore(ckpt_dir, cfg, mesh2, comm, oc,
                                    reselect=True, tune_db_path=db_path,
                                    topology=topo)
@@ -813,6 +814,7 @@ print("LM RANK-LOSS OK", cc1.mode.value, [round(x, 4) for x in h1])
 _TRAIN_COMMON = """
 import dataclasses, json
 import jax, numpy as np, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.configs.registry import get_smoke_config
 from repro.core.config import CommConfig
 from repro.data.pipeline import DataConfig
@@ -825,7 +827,7 @@ oc = adamw.OptConfig(lr=1e-3, zero1=False)
 data = DataConfig(vocab_size=cfg.vocab_size, seq_len=32, global_batch=4)
 
 def fresh_session():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     return setup.build_session(cfg, mesh, CommConfig(), oc=oc)
 """
 

@@ -1,5 +1,6 @@
 """Per-kernel validation: shape/dtype sweeps against the pure-jnp oracles,
-run in Pallas interpret mode on CPU (the kernels target TPU)."""
+run in Pallas interpret mode on CPU (the kernels target TPU; their
+compiles for a described v5e are in test_chip_compile.py)."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -30,7 +31,7 @@ def test_flash_attention_sweep(shape, dtype, mode):
     kwargs = {"causal": dict(causal=True),
               "full": dict(causal=False),
               "window": dict(causal=True, window=37)}[mode]
-    out = ops.flash_attention(q, k, v, **kwargs)
+    out = ops.flash_attention(q, k, v, interpret=True, **kwargs)
     ref = ops.flash_attention_reference(q, k, v, **kwargs)
     tol = 3e-5 if dtype == jnp.float32 else 2e-2
     np.testing.assert_allclose(np.asarray(out, np.float32),
@@ -43,7 +44,7 @@ def test_flash_attention_cross_lengths():
     q = jnp.asarray(rng.randn(1, 64, 2, 32), jnp.float32)
     k = jnp.asarray(rng.randn(1, 300, 2, 32), jnp.float32)
     v = jnp.asarray(rng.randn(1, 300, 2, 32), jnp.float32)
-    out = ops.flash_attention(q, k, v, causal=False)
+    out = ops.flash_attention(q, k, v, causal=False, interpret=True)
     ref = ops.flash_attention_reference(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=3e-5)
 
@@ -67,7 +68,7 @@ def test_ssd_scan_sweep(dims):
     a = -jnp.asarray(np.abs(rng.randn(H)) + 0.5, jnp.float32)
     b = jnp.asarray(rng.randn(B, S, 1, N), jnp.float32)
     c = jnp.asarray(rng.randn(B, S, 1, N), jnp.float32)
-    y_k, h_k = ops.ssd_chunked(x, dt, a, b, c, chunk)
+    y_k, h_k = ops.ssd_chunked(x, dt, a, b, c, chunk, interpret=True)
     y_r, h_r = ssd_chunked_ref(x, dt, a, b, c, chunk)
     np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r),
                                atol=1e-4, rtol=1e-4)
@@ -112,11 +113,11 @@ def test_quant_roundtrip_sweep(n, dtype):
     from repro.kernels.quant.ref import quantize_ref
     rng = np.random.RandomState(0)
     x = jnp.asarray(rng.randn(n) * 3, dtype)
-    q, s = ops.quantize(x)
+    q, s = ops.quantize(x, interpret=True)
     qr, sr = quantize_ref(x)
     # allow ±1 code at exact rounding ties (kernel fuses the divide)
     assert np.abs(np.asarray(q, np.int32) - np.asarray(qr, np.int32)).max() <= 1
-    xd = ops.dequantize(q, s, (n,), dtype)
+    xd = ops.dequantize(q, s, (n,), dtype, interpret=True)
     err = np.abs(np.asarray(xd, np.float32) - np.asarray(x, np.float32)).max()
     scale_bound = float(np.asarray(s).max())
     # bf16 output adds its own rounding (8-bit mantissa) on top of the
@@ -164,6 +165,7 @@ def test_swe_step_sweep(E):
     et = jnp.asarray(rng.randint(0, 3, (E, 3)), jnp.int32)
     area = jnp.asarray(np.abs(rng.randn(E)) * 1e-3 + 1e-4, jnp.float32)
     valid = jnp.asarray((rng.rand(E) > 0.05).astype(np.float32))
-    out = ops.swe_step(u, u_n, nx, ny, et, area, valid, 1.0, dt=1e-4)
+    out = ops.swe_step(u, u_n, nx, ny, et, area, valid, 1.0, dt=1e-4,
+                       interpret=True)
     ref = swe_step_ref(u, u_n, nx, ny, et, area, valid, 1.0, dt=1e-4)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)

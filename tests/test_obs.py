@@ -303,17 +303,17 @@ os.environ["REPRO_TRACE"] = {trace_mode!r}
 import jax, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core import CommConfig, CommMode, Transport, Communicator, collectives
 from repro.obs import trace
 
-mesh = jax.make_mesh((2,), ("x",))
+mesh = make_mesh((2,), ("x",))
 comm = Communicator.from_mesh(mesh, "x")
 x = np.random.RandomState(7).randn(2, 384).astype(np.float32)
 cfg = CommConfig(mode=CommMode.STREAMING, transport=Transport.ORDERED,
                  chunk_bytes=512, window=1)
 
-@partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
+@partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
 def g(xs):
     return collectives.sendrecv(xs[0], comm.ring_perm(), comm, cfg)[None]
 
@@ -352,16 +352,16 @@ os.environ["REPRO_TRACE"] = "chrome:" + {path!r}
 import jax, numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core import CommConfig, CommMode, Communicator, collectives
 from repro.obs import trace
 
-mesh = jax.make_mesh((2,), ("x",))
+mesh = make_mesh((2,), ("x",))
 comm = Communicator.from_mesh(mesh, "x")
 x = np.arange(2 * 256, dtype=np.float32).reshape(2, 256)
 cfg = CommConfig(mode=CommMode.STREAMING, chunk_bytes=512)
 
-@partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
+@partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
 def g(xs):
     return collectives.sendrecv(xs[0], comm.ring_perm(), comm, cfg)[None]
 

@@ -310,30 +310,30 @@ import numpy as np
 import jax
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core import plans
 from repro.core.config import (CommConfig, CommMode, Scheduling, Transport)
 from repro.core.communicator import Communicator
 from repro.core import collectives
 
-mesh = jax.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 comm = Communicator.from_mesh(mesh, "x")
 x = np.random.RandomState(0).randn(8, 130).astype(np.float32)
 
 def run_all(cfg):
     results = []
-    @partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
     def p2p(xs):
         return collectives.sendrecv(xs[0], comm.ring_perm(), comm, cfg)[None]
     results.append(np.asarray(p2p(x)))
     rounds = [comm.ring_perm(1), comm.reverse_ring_perm(1), comm.ring_perm(2)]
-    @partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
     def mn(xs):
         outs = collectives.multi_neighbor_exchange(
             [xs[0]] * len(rounds), rounds, comm, cfg)
         return sum(outs)[None]
     results.append(np.asarray(mn(x)))
-    @partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
     def ar(xs):
         import dataclasses
         rcfg = dataclasses.replace(cfg, algorithm="ring")
@@ -372,11 +372,11 @@ print("PLAN PARITY OK")
 
 def test_warm_sweep_reuses_programs_and_is_faster():
     out = run_multidevice("""
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core import plans
 from repro.tune import TuneDB, run_sweep
 
-mesh = compat.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 cold, warm = {}, {}
 db = run_sweep(mesh=mesh, collectives=("sendrecv",), sizes=(1024,),
                fast=True, max_configs=4, reps=1, inner=2, stats=cold)
@@ -403,25 +403,25 @@ import numpy as np
 import jax
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core import plans, collectives
 from repro.core.communicator import Communicator
 from repro.core.config import CommConfig, CommMode, Scheduling, Transport
 
 plans.reset_stats()
-mesh = jax.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 comm = Communicator.from_mesh(mesh, "x")
 x = np.random.RandomState(0).randn(8, 130).astype(np.float32)
 cfg = CommConfig(mode=CommMode.STREAMING, scheduling=Scheduling.FUSED,
                  transport=Transport.ORDERED, chunk_bytes=512, window=2)
 
-@partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
+@partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
 def p2p(xs):
     return collectives.sendrecv(xs[0], comm.ring_perm(), comm, cfg)[None]
 
 rcfg = dataclasses.replace(cfg, algorithm="ring")
 
-@partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
+@partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"))
 def ar(xs):
     return collectives.all_reduce(xs[0], comm, rcfg)[None]
 

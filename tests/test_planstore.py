@@ -17,29 +17,19 @@ def _planstore():
     return planstore
 
 
-def _unwire_jax():
-    """Detach the JAX compilation cache from any tmp dir a test wired."""
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir", None)
-    except Exception:
-        pass
-
-
 @pytest.fixture
 def disk_store(tmp_path, monkeypatch):
     """plans cache + planstore activated on a fresh tmp dir, fully undone."""
     planstore = _planstore()
     from repro.core import plans
     monkeypatch.delenv(planstore.ENV_VAR, raising=False)
-    planstore.configure(str(tmp_path), wire_jax=False)
+    planstore.configure(str(tmp_path))
     plans.clear_cache()
     plans.reset_stats()
-    yield planstore.active(wire_jax=False)
-    planstore.configure(None, wire_jax=False)
+    yield planstore.active()
+    planstore.configure(None)
     plans.clear_cache()
     plans.reset_stats()
-    _unwire_jax()
 
 
 # ----------------------------------------------------------------------
@@ -241,22 +231,22 @@ def test_corrupt_program_entry_is_miss(tmp_path):
 def test_env_and_configure_control(tmp_path, monkeypatch):
     planstore = _planstore()
     monkeypatch.delenv(planstore.ENV_VAR, raising=False)
-    planstore.configure(None, wire_jax=False)
-    assert planstore.active(wire_jax=False) is None
+    planstore.configure(None)
+    assert planstore.active() is None
 
     monkeypatch.setenv(planstore.ENV_VAR, str(tmp_path / "via-env"))
-    st = planstore.active(wire_jax=False)
+    st = planstore.active()
     assert st is not None and st.root == tmp_path / "via-env"
 
     # explicit empty string disables even with the env var set
-    assert planstore.configure("", wire_jax=False) is None
-    assert planstore.active(wire_jax=False) is None
+    assert planstore.configure("") is None
+    assert planstore.active() is None
 
     # clearing the override hands control back to the env, then to nothing
-    planstore.configure(None, wire_jax=False)
-    assert planstore.active(wire_jax=False) is not None
+    planstore.configure(None)
+    assert planstore.active() is not None
     monkeypatch.delenv(planstore.ENV_VAR)
-    assert planstore.active(wire_jax=False) is None
+    assert planstore.active() is None
 
 
 def test_inert_without_directory(monkeypatch):
@@ -265,7 +255,7 @@ def test_inert_without_directory(monkeypatch):
     planstore = _planstore()
     from repro.core import plans
     monkeypatch.delenv(planstore.ENV_VAR, raising=False)
-    planstore.configure(None, wire_jax=False)
+    planstore.configure(None)
     plans.clear_cache()
     plans.reset_stats()
     from repro.core.config import CommConfig

@@ -238,13 +238,13 @@ import itertools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core import reliable, streaming
 from repro.core.config import (CommConfig, CommMode, Reliability,
                                Scheduling, Transport)
 from repro.obs import metrics as obs_metrics
 
-mesh = compat.make_mesh((4,), ("x",))
+mesh = make_mesh((4,), ("x",))
 perm = [(i, (i + 1) % 4) for i in range(4)]
 N = 8 * 128
 x = jnp.arange(4 * N, dtype=jnp.float32).reshape(4, N) * 0.37 + 1.0
@@ -282,9 +282,9 @@ def run(path, cfg):
                 consume=lambda c, i, m: c + jnp.sum(m),
                 init=jnp.float32(0.0))
             return (msg + carry)[None]
-    f = jax.jit(compat.shard_map(body, mesh=mesh,
-                                 in_specs=spec, out_specs=spec,
-                                 check_vma=False))
+    f = jax.jit(jax.shard_map(body, mesh=mesh,
+                              in_specs=spec, out_specs=spec,
+                              check_vma=False))
     return np.asarray(f(x))
 
 reg = obs_metrics.registry()

@@ -13,7 +13,7 @@ def test_prefill_matches_forward(arch):
     out = run_multidevice("""
 import dataclasses
 import jax, numpy as np, jax.numpy as jnp
-from repro import compat
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro.configs.registry import get_smoke_config
 from repro.core.config import CommConfig
@@ -24,7 +24,7 @@ from repro.train import serve as serve_mod
 ARCH = {arch!r}
 cfg = dataclasses.replace(get_smoke_config(ARCH), dtype=jnp.float32)
 comm = CommConfig()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 sess = setup.build_session(cfg, mesh, comm, concrete=True)
 rng = np.random.RandomState(0)
 B, S = 4, 32
@@ -38,7 +38,7 @@ if cfg.family == "audio":
     batch["frames"] = jnp.asarray(rng.randn(B, S, cfg.frontend_dim), jnp.float32)
 state = pre_fn(sess.params, batch)
 vocab_sharded = cfg.vocab_size % 4 == 0
-fwd = jax.jit(compat.shard_map(
+fwd = jax.jit(jax.shard_map(
     lambda p, b: transformer.forward(p, b, rt, train=False).logits,
     mesh=mesh,
     in_specs=(sess.param_spec, jax.tree.map(lambda _: P(("data",)), batch)),
@@ -58,7 +58,7 @@ def test_decode_matches_extended_prefill():
     out = run_multidevice("""
 import dataclasses
 import jax, numpy as np, jax.numpy as jnp
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.configs.registry import get_smoke_config
 from repro.core.config import CommConfig
 from repro.launch import setup, input_specs as isp
@@ -66,7 +66,7 @@ from repro.train import serve as serve_mod
 
 cfg = dataclasses.replace(get_smoke_config("qwen3-8b"), dtype=jnp.float32)
 comm = CommConfig()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 sess = setup.build_session(cfg, mesh, comm, concrete=True)
 rng = np.random.RandomState(0)
 B, S, GEN = 4, 24, 4
@@ -109,6 +109,7 @@ def test_prefill_spec_at_prompt_length():
     out = run_multidevice("""
 import dataclasses
 import jax, numpy as np, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.configs.registry import get_smoke_config
 from repro.core.config import CommConfig
 from repro.launch import setup, input_specs as isp
@@ -116,7 +117,7 @@ from repro.train import serve as serve_mod
 
 cfg = dataclasses.replace(get_smoke_config("qwen3-8b"), dtype=jnp.float32)
 comm = CommConfig()
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 sess = setup.build_session(cfg, mesh, comm, concrete=True)
 rng = np.random.RandomState(0)
 B, S, MAX = 4, 12, 24
@@ -163,6 +164,7 @@ def test_auto_comm_selects_per_phase():
     out = run_multidevice("""
 import dataclasses, tempfile, os
 import jax, numpy as np, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from repro.configs.registry import get_smoke_config
 from repro.core.config import CommConfig, CommMode, Scheduling, Transport
 from repro.launch import setup, input_specs as isp
@@ -171,7 +173,7 @@ from repro.tune.db import TuneDB, TuneEntry, topology_key
 from repro.tune.space import config_to_dict
 
 cfg = dataclasses.replace(get_smoke_config("qwen3-8b"), dtype=jnp.float32)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 topo = topology_key(mesh)
 
 # Engineered DB: the decode_step loop says the small-chunk overlapped

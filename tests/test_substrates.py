@@ -85,12 +85,13 @@ def test_preemption_guard_drains_training(tmp_path):
     from repro.core.config import CommConfig
     from repro.data.pipeline import DataConfig
     from repro.launch import setup
+    from repro.launch.mesh import make_mesh
     from repro.optim import adamw
     from repro.train import loop as loop_mod
     from repro.runtime import fault_tolerance as ft
 
     cfg = dataclasses.replace(get_smoke_config("qwen3-8b"), dtype=jnp.float32)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sess = setup.build_session(cfg, mesh, CommConfig(),
                                oc=adamw.OptConfig(lr=1e-3, zero1=False))
     # patch: trigger preemption after 3 steps via the guard's request()
@@ -125,6 +126,7 @@ def test_elastic_restore_reshards():
     out = run_multidevice("""
 import dataclasses, tempfile
 import jax, numpy as np, jax.numpy as jnp
+from repro.launch.mesh import make_mesh
 from jax.sharding import PartitionSpec as P
 from repro.configs.registry import get_smoke_config
 from repro.core.config import CommConfig
@@ -140,18 +142,18 @@ rng = np.random.RandomState(0)
 batch = {"tokens": jnp.asarray(rng.randint(0, cfg.vocab_size, (4, 32))),
          "labels": jnp.asarray(rng.randint(0, cfg.vocab_size, (4, 32)))}
 
-mesh1 = jax.make_mesh((2, 4), ("data", "model"))
+mesh1 = make_mesh((2, 4), ("data", "model"))
 sess = setup.build_session(cfg, mesh1, comm, oc=oc)
 bspec = jax.tree.map(lambda _: P(("data",)), batch)
 step = setup.make_sharded_train_step(sess, donate=False)(bspec)
-p, o = sess.params, sess.opt_state
+p, o = sess.params, setup.init_opt_state(sess)
 for _ in range(3):
     p, o, m = step(p, o, batch)
 tmp = tempfile.mkdtemp()
 Checkpointer(tmp).save(3, p)
 
 # "failure": only 4 devices remain -> 2x2 mesh
-mesh2 = jax.make_mesh((2, 2), ("data", "model"))
+mesh2 = make_mesh((2, 2), ("data", "model"))
 sess2, start = elastic_restore(tmp, cfg, mesh2, comm, oc)
 assert start == 3
 # params identical after resharding

@@ -62,6 +62,7 @@ def test_hypothesis_partition_balance():
 def test_partitioned_equals_single_and_modes():
     out = run_multidevice("""
 import jax, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.core.config import CommConfig, CommMode, BASELINE_CONFIG
 from repro.swe import driver
 from repro.swe.partition import _rcb
@@ -76,11 +77,11 @@ def flatten(sim, s):
         counts[p] += 1
     return vals
 
-mesh1 = jax.make_mesh((1,), ("data",))
+mesh1 = make_mesh((1,), ("data",))
 sim1 = driver.build_simulation(500, mesh1, CommConfig())
 v1 = flatten(sim1, np.asarray(driver.make_sim_runner(sim1, 20)(sim1.state, 0.0)))
 
-mesh8 = jax.make_mesh((8,), ("data",))
+mesh8 = make_mesh((8,), ("data",))
 for cfg in (CommConfig(), CommConfig(mode=CommMode.BUFFERED)):
     sim8 = driver.build_simulation(500, mesh8, cfg)
     v8 = flatten(sim8, np.asarray(driver.make_sim_runner(sim8, 20)(sim8.state, 0.0)))
@@ -100,9 +101,10 @@ print("SWE PARITY OK")
 def test_mass_conservation_multidevice():
     out = run_multidevice("""
 import jax, numpy as np
+from repro.launch.mesh import make_mesh
 from repro.core.config import CommConfig
 from repro.swe import driver
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 sim = driver.build_simulation(600, mesh, CommConfig())
 m0 = float(np.sum(np.asarray(sim.state)[..., 0] * sim.pm.area * sim.pm.valid))
 s = driver.make_sim_runner(sim, 50)(sim.state, 0.0)

@@ -456,28 +456,29 @@ def test_torus_parity_matrix_perm_collectives():
     shapes x placements x (mode, scheduling, transport)."""
     out = run_multidevice("""
 import dataclasses
+import jax
 import numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core import collectives
 from repro.core.communicator import Communicator
 from repro.core.config import CommConfig, CommMode, Scheduling, Transport
 from repro.core.topology import TorusSpec, snake_placement
 
-mesh = compat.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 x = np.random.RandomState(0).randn(8, 66).astype(np.float32)
 
 def run_all(comm, cfg):
     results = []
-    @partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
              check_vma=False)
     def p2p(xs):
         return collectives.sendrecv(
             xs[0], [(i, (i + 3) % 8) for i in range(8)], comm, cfg)[None]
     results.append(np.asarray(p2p(x)))
     rounds = [comm.ring_perm(1), comm.reverse_ring_perm(1), comm.ring_perm(2)]
-    @partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
              check_vma=False)
     def mn(xs):
         outs = collectives.multi_neighbor_exchange(
@@ -485,7 +486,7 @@ def run_all(comm, cfg):
         return sum(outs)[None]
     results.append(np.asarray(mn(x)))
     rcfg = dataclasses.replace(cfg, algorithm="ring")
-    @partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
              check_vma=False)
     def ar(xs):
         return collectives.all_reduce(xs[0], comm, rcfg)[None]
@@ -531,16 +532,17 @@ def test_torus_parity_a2a_hierarchical_and_cache_bypass():
     out = run_multidevice("""
 import os
 import dataclasses
+import jax
 import numpy as np
 from functools import partial
 from jax.sharding import PartitionSpec as P
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core import collectives, plans
 from repro.core.communicator import Communicator
 from repro.core.config import CommConfig, CommMode, Scheduling, Transport
 from repro.core.topology import TorusSpec
 
-mesh = compat.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 x = np.random.RandomState(1).randn(8, 64).astype(np.float32)
 
 flat = Communicator.from_mesh(mesh, "x")
@@ -548,7 +550,7 @@ spec = TorusSpec((2, 4))
 torus = flat.with_topology(spec)
 
 def a2a(comm, cfg):
-    @partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
              check_vma=False)
     def f(xs):
         return collectives.all_to_all(
@@ -561,14 +563,14 @@ for cfg in (CommConfig(),
     assert a2a(flat, cfg).tobytes() == a2a(torus, cfg).tobytes(), cfg
 
 # hierarchical: 2-axis mesh, inner communicator placed on a 2x2 torus
-mesh2 = compat.make_mesh((4, 2), ("inner", "outer"))
+mesh2 = make_mesh((4, 2), ("inner", "outer"))
 inner_flat = Communicator.from_mesh(mesh2, "inner")
 inner_torus = inner_flat.with_topology(TorusSpec((2, 2)))
 outer = Communicator.from_mesh(mesh2, "outer")
 x2 = np.random.RandomState(2).randn(8, 48).astype(np.float32)
 
 def hier(inner, cfg):
-    @partial(compat.shard_map, mesh=mesh2,
+    @partial(jax.shard_map, mesh=mesh2,
              in_specs=P(("inner", "outer")), out_specs=P(("inner", "outer")),
              check_vma=False)
     def f(xs):
@@ -581,13 +583,13 @@ for cfg in (CommConfig(algorithm="ring", chunk_bytes=512), CommConfig()):
 
 # plan-cache bypass parity under the torus transport
 def perm_ops(cfg):
-    @partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
              check_vma=False)
     def p2p(xs):
         return collectives.sendrecv(
             xs[0], [(i, (i + 3) % 8) for i in range(8)], torus, cfg)[None]
     rounds = [torus.ring_perm(1), torus.ring_perm(2)]
-    @partial(compat.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
+    @partial(jax.shard_map, mesh=mesh, in_specs=P("x"), out_specs=P("x"),
              check_vma=False)
     def mn(xs):
         outs = collectives.multi_neighbor_exchange(
@@ -618,13 +620,13 @@ def test_measured_latency_grows_with_hop_distance():
     sweep measures a 3-hop translation strictly slower than the direct link
     — each extra hop is one more executed permute of the full payload."""
     out = run_multidevice("""
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core.config import OPTIMIZED_CONFIG
 from repro.core.communicator import Communicator
 from repro.core.topology import TorusSpec
 from repro.tune.sweep import _build_op, _time_program
 
-mesh = compat.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 comm = Communicator.from_mesh(mesh, "x", topo=TorusSpec((2, 4)))
 # One device-scheduled streaming config (dispatch amortized over the
 # compiled loop): the timing is dominated by the permutes themselves, and
@@ -650,7 +652,7 @@ def test_swe_driver_on_torus_matches_flat_and_selects_per_edge():
     both the serial and the overlapped schedule."""
     out = run_multidevice("""
 import numpy as np, jax, dataclasses, tempfile
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.core.config import CommConfig, Scheduling
 from repro.core.communicator import Communicator
 from repro.core.topology import TorusSpec
@@ -672,7 +674,7 @@ for msg in (1024, 1 << 16):
                          us_per_call=us2, hops=2))
 path = tempfile.mktemp(suffix=".json"); db.save(path)
 
-dmesh = compat.make_mesh((8,), ("data",))
+dmesh = make_mesh((8,), ("data",))
 spec = TorusSpec((2, 4))
 sim = driver.build_simulation(400, dmesh, "auto", tune_db_path=path,
                               topology=spec)

@@ -469,11 +469,11 @@ def test_program_cache_key_separates_mesh_factorizations():
 
 def test_e2e_sweep_records_consumer_loop(tmp_path):
     out = run_multidevice("""
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.tune import TuneDB, run_sweep, select_config
 from repro.tune.sweep import sweep_summary
 
-mesh = compat.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 stats = {}
 db = run_sweep(mesh=mesh, collectives=("all_reduce",), sizes=(16384,),
                fast=True, max_configs=6, reps=1, inner=2,
@@ -497,14 +497,14 @@ def test_moe_all_to_all_e2e_sweep_selects_measured_best(tmp_path):
     entry: an e2e-objective all_to_all sweep must record consumer-loop times
     and select_config(objective='e2e') must return the measured winner."""
     out = run_multidevice("""
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.tune import TuneDB, run_sweep, select_config
 from repro.tune.sweep import CONSUMERS, consumer_flops
 
 assert CONSUMERS["all_to_all"] == ("moe_loop",)
 assert consumer_flops("all_to_all", 1 << 14) > 0
 
-mesh = compat.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 stats = {}
 db = run_sweep(mesh=mesh, collectives=("all_to_all",), sizes=(16384,),
                fast=True, max_configs=5, reps=1, inner=2,
@@ -743,10 +743,10 @@ def test_chunk_aware_prediction_prices_small_segments():
 
 def test_sweep_new_collectives_and_pruning_e2e(tmp_path):
     out = run_multidevice("""
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.tune import CalibrationResult, TuneDB, run_sweep
 
-mesh = compat.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 cal = CalibrationResult(l_k_host=30e-6, l_k_fused=0.5e-6,
                         link_latency=1e-6, link_bw=50e9, staging_bw=819e9,
                         n_points=16, rms_rel_err=0.05)
@@ -810,11 +810,11 @@ def test_stall_fraction_monotone_in_l_k():
 def test_sweep_select_and_auto_driver_e2e(tmp_path):
     out = run_multidevice(f"""
 import jax
-from repro import compat
+from repro.launch.mesh import make_mesh
 from repro.tune import TuneDB, run_sweep, select_config
 from repro.core.config import CommConfig
 
-mesh = compat.make_mesh((8,), ("x",))
+mesh = make_mesh((8,), ("x",))
 db = run_sweep(mesh=mesh, collectives=("sendrecv",), sizes=(1024,),
                fast=True, max_configs=2, reps=1, inner=2)
 assert len(db) >= 1, "sweep produced no entries"
@@ -824,7 +824,7 @@ assert isinstance(cfg, CommConfig)
 
 # the SWE driver consumes the same TuneDB via comm_cfg="auto"
 from repro.swe import driver
-dmesh = compat.make_mesh((8,), ("data",))
+dmesh = make_mesh((8,), ("data",))
 sim = driver.build_simulation(400, dmesh, "auto", tune_db_path=path)
 assert isinstance(sim.comm_cfg, CommConfig)
 s = driver.make_sim_runner(sim, 3)(sim.state, 0.0)
