@@ -37,9 +37,10 @@ def _nbytes(x) -> int:
 
 def _record_edges(comm: Communicator, perm, nbytes: int) -> None:
     """Per-edge byte accounting: every edge moves ``nbytes``, counted under
-    its torus hop distance (the per-edge axis of the paper's Fig. 9)."""
+    its torus hop distance (the per-edge axis of the paper's Fig. 9).
+    Counted when the exchange is traced, so once per compilation, not once
+    per execution."""
     reg = obs_metrics.registry()
-    reg.counter("comm.bytes").inc(nbytes * len(perm))
     for s, d in perm:
         reg.counter("comm.edge_bytes",
                     hops=comm.torus_hops(int(s), int(d))).inc(nbytes)
@@ -95,13 +96,13 @@ def sendrecv(x: jnp.ndarray, perm: Sequence[tuple[int, int]],
     hops = comm.max_hops(perm)
     _record_edges(comm, perm, nbytes)
     perm = topology.routed_perm(comm, perm)
-    with obs_trace.span("sendrecv", cat="collective", nbytes=nbytes,
-                        hops=hops, edges=len(perm.edges)
-                        if isinstance(perm, topology.RoutedPerm)
-                        else len(perm),
-                        mode=cfg.mode, transport=cfg.transport,
-                        scheduling=cfg.scheduling,
-                        reliability=cfg.reliability):
+    with obs_trace.scope("sendrecv", cat="collective", nbytes=nbytes,
+                         hops=hops, edges=len(perm.edges)
+                         if isinstance(perm, topology.RoutedPerm)
+                         else len(perm),
+                         mode=cfg.mode, transport=cfg.transport,
+                         scheduling=cfg.scheduling,
+                         reliability=cfg.reliability):
         if cfg.mode == CommMode.STREAMING:
             return streaming.chunked_permute(x, perm, comm.axis, cfg)
         return streaming.buffered_permute(x, perm, comm.axis, cfg)
@@ -155,8 +156,7 @@ def multi_neighbor_exchange(payloads: Sequence[jnp.ndarray],
         # Degenerate empty pattern: behave like the uniform-config call
         # (no rounds means no config is ever consulted).
         cfg = round_cfgs[0] if round_cfgs else CommConfig()
-    obs_metrics.registry().counter("comm.exchange_rounds").inc(len(rounds))
-    exchange_span = obs_trace.span(
+    exchange_scope = obs_trace.scope(
         "multi_neighbor", cat="collective", rounds=len(rounds),
         hops=comm.max_hops([e for r in rounds for e in r]),
         nbytes=_nbytes(payloads[0]) if payloads else 0,
@@ -183,7 +183,7 @@ def multi_neighbor_exchange(payloads: Sequence[jnp.ndarray],
         # Virtual-torus lowering happens per round inside the engine so the
         # double-buffered ack chain still runs per buffer.
         rounds = [topology.routed_perm(comm, perm) for perm in rounds]
-        with exchange_span:
+        with exchange_scope:
             carry, received = streaming.double_buffered_exchange(
                 payloads, rounds, comm.axis, cfg, consume=consume, init=init,
                 chunk_consume=chunk_consume, chunk_align=chunk_align)
@@ -192,7 +192,7 @@ def multi_neighbor_exchange(payloads: Sequence[jnp.ndarray],
         return received
     received = []
     prev = None
-    with exchange_span:
+    with exchange_scope:
         for r, (payload, perm) in enumerate(zip(payloads, rounds)):
             rcfg = round_cfgs[r] if round_cfgs is not None else cfg
             if rcfg.transport == Transport.ORDERED and prev is not None:
@@ -328,14 +328,14 @@ def all_reduce(x: jnp.ndarray, comm: Communicator, cfg: CommConfig,
     the logical cotangent.  shard_map's default transpose (psum again, or the
     ring algorithm's permute chain) would compound a tp× factor per combine.
     """
-    with obs_trace.span("all_reduce", cat="collective", op=op,
-                        nbytes=_nbytes(x), algorithm=cfg.algorithm,
-                        mode=cfg.mode, transport=cfg.transport,
-                        scheduling=cfg.scheduling,
-                        reliability=cfg.reliability,
-                        hops=comm.max_hops(comm.ring_perm())
-                        if cfg.algorithm == "ring" and comm.single_axis
-                        else 1):
+    with obs_trace.scope("all_reduce", cat="collective", op=op,
+                         nbytes=_nbytes(x), algorithm=cfg.algorithm,
+                         mode=cfg.mode, transport=cfg.transport,
+                         scheduling=cfg.scheduling,
+                         reliability=cfg.reliability,
+                         hops=comm.max_hops(comm.ring_perm())
+                         if cfg.algorithm == "ring" and comm.single_axis
+                         else 1):
         if op == "sum":
             @jax.custom_vjp
             def f(v):
@@ -360,10 +360,10 @@ def all_reduce(x: jnp.ndarray, comm: Communicator, cfg: CommConfig,
 
 def all_gather(x: jnp.ndarray, comm: Communicator, cfg: CommConfig,
                axis: int = 0, tiled: bool = True) -> jnp.ndarray:
-    with obs_trace.span("all_gather", cat="collective", nbytes=_nbytes(x),
-                        algorithm=cfg.algorithm, mode=cfg.mode,
-                        transport=cfg.transport, scheduling=cfg.scheduling,
-                        reliability=cfg.reliability):
+    with obs_trace.scope("all_gather", cat="collective", nbytes=_nbytes(x),
+                         algorithm=cfg.algorithm, mode=cfg.mode,
+                         transport=cfg.transport, scheduling=cfg.scheduling,
+                         reliability=cfg.reliability):
         if cfg.algorithm == "ring" and comm.single_axis:
             stacked = ring_all_gather(x, comm, cfg)
             if not tiled:
@@ -376,11 +376,11 @@ def all_gather(x: jnp.ndarray, comm: Communicator, cfg: CommConfig,
 
 def reduce_scatter(x: jnp.ndarray, comm: Communicator, cfg: CommConfig,
                    op: str = "sum") -> jnp.ndarray:
-    with obs_trace.span("reduce_scatter", cat="collective",
-                        nbytes=_nbytes(x), algorithm=cfg.algorithm,
-                        mode=cfg.mode, transport=cfg.transport,
-                        scheduling=cfg.scheduling,
-                        reliability=cfg.reliability):
+    with obs_trace.scope("reduce_scatter", cat="collective",
+                         nbytes=_nbytes(x), algorithm=cfg.algorithm,
+                         mode=cfg.mode, transport=cfg.transport,
+                         scheduling=cfg.scheduling,
+                         reliability=cfg.reliability):
         if cfg.algorithm == "ring" and comm.single_axis:
             return ring_reduce_scatter(x, comm, cfg, op)
         assert op == "sum"
@@ -397,10 +397,10 @@ def all_to_all(x: jnp.ndarray, comm: Communicator, cfg: CommConfig,
     so the dispatch/combine overlaps its own transfer — bitwise-identical
     to the fused op.
     """
-    with obs_trace.span("all_to_all", cat="collective", nbytes=_nbytes(x),
-                        mode=cfg.mode, transport=cfg.transport,
-                        scheduling=cfg.scheduling,
-                        reliability=cfg.reliability):
+    with obs_trace.scope("all_to_all", cat="collective", nbytes=_nbytes(x),
+                         mode=cfg.mode, transport=cfg.transport,
+                         scheduling=cfg.scheduling,
+                         reliability=cfg.reliability):
         if (cfg.scheduling == Scheduling.OVERLAPPED
                 and cfg.mode == CommMode.STREAMING):
             return streaming.chunked_all_to_all(x, comm, cfg, split_axis,
@@ -432,12 +432,12 @@ def hierarchical_all_reduce(x: jnp.ndarray, inner: Communicator,
     of the paper's switch-topology tuning.  Requires leading dim divisible by
     the inner size; falls back to flat psum otherwise.
     """
-    with obs_trace.span("hierarchical_all_reduce", cat="collective",
-                        nbytes=_nbytes(x), inner=inner.size,
-                        outer=outer.size, mode=cfg.mode,
-                        transport=cfg.transport,
-                        scheduling=cfg.scheduling,
-                        reliability=cfg.reliability):
+    with obs_trace.scope("hierarchical_all_reduce", cat="collective",
+                         nbytes=_nbytes(x), inner=inner.size,
+                         outer=outer.size, mode=cfg.mode,
+                         transport=cfg.transport,
+                         scheduling=cfg.scheduling,
+                         reliability=cfg.reliability):
         flat = x.reshape(-1)
         n = inner.size
         pad = (-flat.shape[0]) % n

@@ -131,9 +131,9 @@ def _reliable_stream(rplan, chunks, perm, axis_name: str, cfg: CommConfig,
     next_flush = 0
     for j, slot in enumerate(rplan.slots):
         payload = chunks[slot.seq]
-        with obs_trace.span("wire.slot", cat="wire", slot=j, of=len(rplan.slots),
-                            seq=slot.seq, action=slot.action,
-                            attempt=slot.attempt):
+        with obs_trace.scope("wire.slot", cat="wire", slot=j,
+                             of=len(rplan.slots), seq=slot.seq,
+                             action=slot.action, attempt=slot.attempt):
             deps = []
             if ordered and j >= cfg.window:
                 deps.append(outs[j - cfg.window])
@@ -182,9 +182,9 @@ def chunked_permute(x: jnp.ndarray, perm: Sequence[tuple[int, int]],
     received = []
     for i in range(n):
         payload = chunks[i]
-        with obs_trace.span("wire.chunk", cat="wire", chunk=i, of=n,
-                            elems=int(payload.size),
-                            acked=int(plan.ack_of[i])):
+        with obs_trace.scope("wire.chunk", cat="wire", chunk=i, of=n,
+                             elems=int(payload.size),
+                             acked=int(plan.ack_of[i])):
             if plan.ack_of[i] >= 0:
                 # Ack chain: chunk i waits until chunk i-window was delivered.
                 payload, _ = lax.optimization_barrier(
@@ -212,7 +212,7 @@ def buffered_permute(x: jnp.ndarray, perm: Sequence[tuple[int, int]],
         _, seq_chunks = _reliable_stream(rplan, [x], perm, axis_name, cfg)
         out = lax.optimization_barrier(seq_chunks[0])
         return out
-    with obs_trace.span("wire.message", cat="wire", elems=int(x.size)):
+    with obs_trace.scope("wire.message", cat="wire", elems=int(x.size)):
         enc, dec = plugins.wire_encode(x, cfg)
         out = jax.tree.map(lambda t: wire_permute(t, axis_name, perm), enc)
         out = lax.optimization_barrier(out)
@@ -253,9 +253,9 @@ def pipelined_consume(x: jnp.ndarray, perm: Sequence[tuple[int, int]],
     received = []
     for i in range(n):
         payload = chunks[i]
-        with obs_trace.span("wire.chunk", cat="wire", chunk=i, of=n,
-                            elems=int(chunk_elems),
-                            acked=int(plan.ack_of[i])):
+        with obs_trace.scope("wire.chunk", cat="wire", chunk=i, of=n,
+                             elems=int(chunk_elems),
+                             acked=int(plan.ack_of[i])):
             if plan.ack_of[i] >= 0:
                 payload, _ = lax.optimization_barrier(
                     (payload, received[plan.ack_of[i]]))
@@ -310,8 +310,8 @@ def double_buffered_exchange(payloads: Sequence[jnp.ndarray],
         buf = bufs[r % 2]
         hops = (perm.max_hops if isinstance(perm, topology.RoutedPerm)
                 else 1)
-        with obs_trace.span("round", cat="collective", round=r, buf=r % 2,
-                            hops=hops, elems=int(payload.size)):
+        with obs_trace.scope("round", cat="collective", round=r, buf=r % 2,
+                             hops=hops, elems=int(payload.size)):
             if cfg.transport == Transport.ORDERED and buf:
                 # Per-buffer ack chain: no cross-buffer serialization.
                 payload, _ = lax.optimization_barrier((payload, buf[-1]))

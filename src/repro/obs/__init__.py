@@ -4,10 +4,12 @@ The telemetry substrate under the comm stack — what lets you *see* where
 communication time goes (the paper's per-configuration/per-edge breakdowns,
 ACCL+'s collective-engine timing feed):
 
-- :mod:`repro.obs.trace`   — low-overhead span tracer (``REPRO_TRACE`` env
+- :mod:`repro.obs.trace`   — the program's tracing API (``REPRO_TRACE`` env
   gate, thread-safe ring buffer, Chrome ``trace_event`` export for
-  Perfetto).  Instrumented through every layer: collectives, wire chunks,
-  driver phases, sweep candidates, watchdog events.
+  Perfetto).  ``span`` times host regions (driver segments and set-up,
+  sweep candidates, watchdog events) and, when on, lands them in a JAX
+  profiler trace; ``scope`` names code under ``jit`` (solver phases,
+  collectives, wire chunks) in the compiled HLO.
 - :mod:`repro.obs.metrics` — always-on registry of counters, gauges, and
   fixed-bucket latency histograms (plan-cache hit/miss, bytes per edge,
   rounds per exchange, sweep candidates pruned, straggler events).
@@ -16,7 +18,8 @@ ACCL+'s collective-engine timing feed):
 """
 from repro.obs import metrics, trace
 from repro.obs.metrics import registry
-from repro.obs.trace import configure, enabled, events, flush, instant, span
+from repro.obs.trace import (configure, enabled, events, flush, instant,
+                             scope, span)
 
 __all__ = ["configure", "enabled", "events", "flush", "instant", "metrics",
-           "registry", "span", "trace"]
+           "registry", "scope", "span", "trace"]

@@ -1,36 +1,46 @@
-"""Low-overhead comm-event span tracer with Chrome ``trace_event`` export.
+"""Span tracer on the JAX profiler's clock, with Chrome ``trace_event`` export.
 
 The paper's argument rests on *seeing* where communication time goes — the
 per-configuration breakdowns of Figs. 9–11 and the per-edge behavior at 48
-FPGAs.  This module is the software analogue: every layer of the comm stack
-(collective entry points, wire chunks, driver phases, watchdog events) emits
-spans into a thread-safe ring buffer, exported as Chrome ``trace_event`` JSON
-viewable in Perfetto (https://ui.perfetto.dev) or ``chrome://tracing``.
+FPGAs.  This module is the program's one tracing API.  Two entry points:
+
+- :func:`span` times a host region (a driver segment, a sweep candidate, a
+  training step).  When tracing is on, the span is stamped into a
+  thread-safe ring buffer on the clock the JAX profiler stamps host events
+  with (``CLOCK_REALTIME``, :func:`time.time_ns`) and also enters
+  ``jax.profiler.TraceAnnotation(name, **args)``, so inside a profiled
+  window it sits in the trace's host plane, on the same timeline as the
+  device operations.
+- :func:`scope` names a region of code that runs under ``jit`` or
+  ``shard_map`` (a solver phase, a collective, a wire chunk).  It always
+  enters ``jax.named_scope(name)``: the name lands in every HLO
+  instruction's ``op_name`` metadata and from there in the device trace,
+  where each operation's time can be put down to its scope.  That is
+  metadata only: no run-time cost and no numeric change.  When tracing is
+  on it also records a structure event in the ring buffer; JAX traces an
+  SPMD program once, so that event times *schedule construction*, once per
+  compilation (one event per exchange round, per wire chunk, with hop
+  distances and byte counts).
 
 Enable with the ``REPRO_TRACE`` environment variable:
 
 - unset / ``0`` — disabled (the default).  :func:`span` returns a shared
-  no-op context manager and :func:`instant` returns immediately: the
-  instrumented code paths are byte-for-byte the seed behavior, no events
-  are recorded, and no buffer exists (asserted by ``tests/test_obs.py``).
+  no-op context manager and :func:`instant` returns immediately: no events
+  are recorded, no buffer exists, no profiler annotation is opened, and
+  JAX is not imported (asserted by ``tests/test_obs.py``).
 - ``1``        — collect spans in memory (read back via :func:`events`).
 - ``chrome:<path>`` — collect and export to ``<path>`` at process exit
   (or on an explicit :func:`flush`).
 
-Span semantics: JAX traces an SPMD program once, so spans emitted inside
-``shard_map``/``jit`` (collective and wire-chunk layers) measure *schedule
-construction* — they record the structure the program will execute (one span
-per exchange round, per wire chunk, with hop distances and byte counts),
-once per compilation.  Host-level spans (sweep candidates, driver segments,
-watchdog steps) measure real wall clock.  Both land on the same timeline;
-the ``cat`` field tells them apart (``collective``/``wire`` = trace-time
-structure, ``sweep``/``driver``/``watchdog`` = wall time).
+The ``cat`` field tells wall-clock spans (``driver``/``setup``/``sweep``/
+``train``/``watchdog``) from trace-time structure events (``phase``/
+``collective``/``wire``).
 
 Tracks: ``rank=`` (when the caller knows it) maps to a Chrome ``pid`` so
 per-rank activity renders as separate process tracks; host threads map to
-``tid`` within a track, and nested ``with span(...)`` blocks on one thread
-nest by time containment — per-round spans sit inside their collective's
-span, per-chunk spans inside their round's.
+``tid`` within a track, and nested blocks on one thread nest by time
+containment — per-round events sit inside their collective's, per-chunk
+events inside their round's.
 """
 from __future__ import annotations
 
@@ -72,12 +82,15 @@ class Tracer:
         self._lock = threading.Lock()
         self._events: deque = deque(maxlen=capacity)
         self._dropped = 0
-        self._t0 = time.perf_counter()
         self._tids: dict[int, int] = {}
 
     # ------------------------------------------------------------------
-    def now_us(self) -> float:
-        return (time.perf_counter() - self._t0) * 1e6
+    @staticmethod
+    def now_us() -> float:
+        """Microseconds since the epoch on ``CLOCK_REALTIME``, the clock the
+        JAX profiler stamps host events with: ring-buffer events and a
+        profiled window share one timeline."""
+        return time.time_ns() / 1e3
 
     def _tid(self) -> int:
         ident = threading.get_ident()
@@ -148,6 +161,9 @@ class Tracer:
 
 _TRACER: Optional[Tracer] = None
 _ATEXIT_REGISTERED = False
+# jax.profiler.TraceAnnotation, imported by configure() when tracing first
+# turns on, so that importing this module does not import JAX.
+_ANNOTATION = None
 
 
 class _NullSpan:
@@ -172,26 +188,40 @@ _NULL_SPAN = _NullSpan()
 class _Span:
     """One live span: records wall time between ``__enter__``/``__exit__``
     and emits a Chrome complete ("X") event.  ``set(**args)`` attaches
-    results known only after the timed region (e.g. the measured latency)."""
-    __slots__ = ("_tracer", "name", "cat", "rank", "args", "_ts")
+    results known only after the timed region (e.g. the measured latency);
+    they reach the ring buffer, not the profiler annotation, whose arguments
+    are fixed when it opens.  ``annotate=False`` keeps the span out of the
+    profiler (structure events recorded at trace time)."""
+    __slots__ = ("_tracer", "name", "cat", "rank", "args", "_ns",
+                 "_annotate", "_annotation")
 
     def __init__(self, tracer: Tracer, name: str, cat: str,
-                 rank: Optional[int], args: dict):
+                 rank: Optional[int], args: dict, annotate: bool = True):
         self._tracer = tracer
         self.name = name
         self.cat = cat
         self.rank = rank
         self.args = args
-        self._ts = 0.0
+        self._ns = 0
+        self._annotate = annotate and _ANNOTATION is not None
+        self._annotation = None
 
     def __enter__(self):
-        self._ts = self._tracer.now_us()
+        self._ns = time.time_ns()
+        if self._annotate:
+            self._annotation = _ANNOTATION(
+                self.name, **{k: _jsonable(v) for k, v in self.args.items()
+                              if v is not None})
+            self._annotation.__enter__()
         return self
 
     def __exit__(self, *exc):
-        self._tracer.complete(self.name, self.cat, self._ts,
-                              self._tracer.now_us() - self._ts,
-                              self.rank, self.args)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+            self._annotation = None
+        end = time.time_ns()
+        self._tracer.complete(self.name, self.cat, self._ns / 1e3,
+                              (end - self._ns) / 1e3, self.rank, self.args)
         return False
 
     def set(self, **args):
@@ -199,11 +229,44 @@ class _Span:
         return self
 
 
+class _Scope:
+    """A named region of code under a JAX trace: ``jax.named_scope(name)``
+    always, and a ring-buffer structure event while tracing is on (decided
+    when the scope is entered, so a scope made ahead of its block follows
+    the tracer's state at that point)."""
+    __slots__ = ("name", "cat", "rank", "args", "_named", "_span")
+
+    def __init__(self, name: str, cat: str, rank: Optional[int], args: dict):
+        self.name = name
+        self.cat = cat
+        self.rank = rank
+        self.args = args
+        self._named = None
+        self._span = None
+
+    def __enter__(self):
+        import jax
+        self._named = jax.named_scope(self.name)
+        self._named.__enter__()
+        t = _TRACER
+        if t is not None:
+            self._span = _Span(t, self.name, self.cat, self.rank, self.args,
+                               annotate=False).__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        if self._span is not None:
+            self._span.__exit__(*exc)
+            self._span = None
+        self._named.__exit__(*exc)
+        return False
+
+
 def configure(mode: Optional[str] = None) -> Optional[Tracer]:
     """(Re)configure the global tracer from ``mode`` (or the ``REPRO_TRACE``
     env var when ``mode`` is None).  Returns the active tracer or None.
     Safe to call at runtime — tests toggle tracing on and off with it."""
-    global _TRACER, _ATEXIT_REGISTERED
+    global _TRACER, _ATEXIT_REGISTERED, _ANNOTATION
     if mode is None:
         mode = os.environ.get(ENV_VAR, "0")
     mode = (mode or "0").strip()
@@ -214,6 +277,9 @@ def configure(mode: Optional[str] = None) -> Optional[Tracer]:
     if mode != "1" and sink is None:
         raise ValueError(f"{ENV_VAR} must be 0, 1, or chrome:<path>, "
                          f"got {mode!r}")
+    if _ANNOTATION is None:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
     _TRACER = Tracer(sink=sink)
     if sink and not _ATEXIT_REGISTERED:
         atexit.register(flush)
@@ -238,7 +304,10 @@ def tracer() -> Optional[Tracer]:
 
 
 def span(name: str, cat: str = "comm", rank: Optional[int] = None, **args):
-    """Context manager timing one region; no-op singleton when disabled.
+    """Context manager timing one host region; no-op singleton when
+    disabled.  When enabled the region is also a profiler annotation of the
+    same name and arguments.  For code under ``jit``/``shard_map`` use
+    :func:`scope`: a span there would time tracing, not execution.
 
     ::
 
@@ -252,6 +321,21 @@ def span(name: str, cat: str = "comm", rank: Optional[int] = None, **args):
     if t is None:
         return _NULL_SPAN
     return _Span(t, name, cat, rank, args)
+
+
+def scope(name: str, cat: str = "phase", rank: Optional[int] = None,
+          **args):
+    """Context manager naming a region of code under ``jit``/``shard_map``:
+    every operation traced inside it carries ``name`` in its HLO
+    ``op_name`` (``jax.named_scope``), whether tracing is on or off.  With
+    tracing on it also records a structure event (once per compilation).
+
+    ::
+
+        with trace.scope("swe.gather"):
+            u_n = ext[neigh_idx]               # op_name .../swe.gather/...
+    """
+    return _Scope(name, cat, rank, args)
 
 
 def instant(name: str, cat: str = "comm", rank: Optional[int] = None,

@@ -194,9 +194,11 @@ def make_step_fn(pm: PartitionedMesh, comm_cfg: CommConfig, axis: str = "data",
                         jnp.where(edge_type[..., None] == 2, u_sea, u_n))
         return rusanov(u, u_r, n)                      # (..., 3edges, 3)
 
-    def fluxes(state, halo, normals, neigh_idx, edge_type, t):
+    def gather(state, halo, neigh_idx):
+        """Each element's three neighbour states, from its own partition or
+        the halo: (..., 3edges, 3)."""
         ext = jnp.concatenate([state, halo], axis=0)   # (E_max+H_max, 3)
-        return edge_fluxes(state, ext[neigh_idx], normals, edge_type, t)
+        return ext[neigh_idx]
 
     def apply_update(state_rows, f, area_rows, valid_rows):
         div = jnp.sum(f, axis=-2)                      # (..., 3)
@@ -206,16 +208,22 @@ def make_step_fn(pm: PartitionedMesh, comm_cfg: CommConfig, axis: str = "data",
         return new.at[..., 0].set(
             jnp.maximum(new[..., 0], 1e-6) * valid_rows)
 
+    # Phases are named scopes (``swe.gather``, ``swe.flux``, ``swe.update``,
+    # ``swe.exchange``): every device operation of the step carries its
+    # phase in its HLO op_name, which the device trace reads.
+
     def step_serial(state, t, area, normals, neigh_idx, edge_type, valid,
                     send_idx, send_mask, recv_slot, boundary_idx):
         # 1. fire exchange (streaming: overlaps with local flux compute)
-        with obs_trace.span("swe.exchange", cat="phase",
-                            rounds=pm.n_rounds):
+        with obs_trace.scope("swe.exchange", rounds=pm.n_rounds):
             halo = exchange(state, send_idx, send_mask, recv_slot)
         # 2+3. fluxes (local edges depend only on state; remote edges read
         # the halo — XLA schedules the permutes against the local part)
-        with obs_trace.span("swe.update", cat="phase"):
-            f = fluxes(state, halo, normals, neigh_idx, edge_type, t)
+        with obs_trace.scope("swe.gather"):
+            u_n = gather(state, halo, neigh_idx)
+        with obs_trace.scope("swe.flux"):
+            f = edge_fluxes(state, u_n, normals, edge_type, t)
+        with obs_trace.scope("swe.update"):
             return apply_update(state, f, area, valid)
 
     def step_overlapped(state, t, area, normals, neigh_idx, edge_type, valid,
@@ -224,25 +232,32 @@ def make_step_fn(pm: PartitionedMesh, comm_cfg: CommConfig, axis: str = "data",
         # data dependency on the exchange, so the scheduler runs this while
         # the chunk permutes are in flight.  Boundary rows come out wrong
         # here and are overwritten below.
-        zero_halo = jnp.zeros((pm.h_max, 3), state.dtype)
-        with obs_trace.span("swe.interior", cat="phase"):
-            f_int = fluxes(state, zero_halo, normals, neigh_idx, edge_type, t)
-            new = apply_update(state, f_int, area, valid)
+        with obs_trace.scope("swe.interior"):
+            with obs_trace.scope("swe.gather"):
+                zero_halo = jnp.zeros((pm.h_max, 3), state.dtype)
+                u_n = gather(state, zero_halo, neigh_idx)
+            with obs_trace.scope("swe.flux"):
+                f_int = edge_fluxes(state, u_n, normals, edge_type, t)
+            with obs_trace.scope("swe.update"):
+                new = apply_update(state, f_int, area, valid)
         # Double-buffered exchange folds rounds into the halo as they land.
-        with obs_trace.span("swe.exchange", cat="phase",
-                            rounds=pm.n_rounds):
+        with obs_trace.scope("swe.exchange", rounds=pm.n_rounds):
             halo = exchange_overlapped(state, send_idx, send_mask, recv_slot)
         # Boundary pass: recompute ONLY the elements with a remote edge
         # against the real halo, then scatter them over the interior result.
         # Padded boundary_idx entries duplicate a real row with identical
         # values, so the scatter stays deterministic.
-        with obs_trace.span("swe.boundary", cat="phase"):
-            ext = jnp.concatenate([state, halo], axis=0)
+        with obs_trace.scope("swe.boundary"):
             b = boundary_idx
-            f_b = edge_fluxes(state[b], ext[neigh_idx[b]], normals[b],
-                              edge_type[b], t)
-            new_b = apply_update(state[b], f_b, area[b], valid[b])
-            return new.at[b].set(new_b)
+            with obs_trace.scope("swe.gather"):
+                u_b = gather(state, halo, neigh_idx[b])
+                state_b, normals_b, edge_type_b, area_b, valid_b = (
+                    a[b] for a in (state, normals, edge_type, area, valid))
+            with obs_trace.scope("swe.flux"):
+                f_b = edge_fluxes(state_b, u_b, normals_b, edge_type_b, t)
+            with obs_trace.scope("swe.update"):
+                new_b = apply_update(state_b, f_b, area_b, valid_b)
+                return new.at[b].set(new_b)
 
     if comm_cfg.scheduling == Scheduling.OVERLAPPED:
         return step_overlapped

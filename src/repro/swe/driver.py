@@ -18,6 +18,7 @@ Three execution modes, mirroring the paper's §3.1/§5 scheduling comparison:
 from __future__ import annotations
 
 import dataclasses
+import itertools
 from functools import partial
 from typing import Optional
 
@@ -108,6 +109,39 @@ def state_digest(sim: "Simulation", state) -> str:
         np.ascontiguousarray(flatten_state(sim, state)).tobytes()).hexdigest()
 
 
+def _resolve_comm(comm_cfg, pm: PartitionedMesh, n_parts: int,
+                  device_mesh: Mesh, tune_db_path, objective: str, topology):
+    """``comm_cfg="auto"`` -> (representative config, per-round configs or
+    None); see :func:`build_simulation`."""
+    from repro.core.collectives import resolve_config
+    from repro.core.communicator import Communicator
+    halo_bytes = int(pm.s_max) * 3 * 4   # (h, hu, hv) f32 per halo element
+    # Worst-case torus hop distance of this partitioning's exchange
+    # pattern — multi-hop edges prefer hop-matched measurements.
+    comm = Communicator(("data",), (n_parts,), topo=topology)
+    edges = [e for r in pm.rounds for e in r]
+    hops = comm.max_hops(edges) if edges else None
+    comm_cfg = resolve_config(comm_cfg, "multi_neighbor", halo_bytes,
+                              mesh=device_mesh, db_path=tune_db_path,
+                              hops=hops, objective=objective,
+                              torus=topology.name if topology else "")
+    # Per-edge selection is a torus feature: the flat mesh keeps its
+    # single worst-case-hop config (no silent behavior change), and the
+    # double-buffered overlapped engine pipelines all rounds under one
+    # config — don't select what can't be applied.
+    if (pm.rounds and topology is not None
+            and comm_cfg.scheduling != Scheduling.OVERLAPPED):
+        per_round = _select_round_configs(pm.rounds, comm, halo_bytes,
+                                          tune_db_path, objective)
+        # One scheduling discipline per step: unify each round's wire
+        # config with the representative's scheduling.
+        per_round = [dataclasses.replace(c, scheduling=comm_cfg.scheduling)
+                     for c in per_round]
+        if any(c != comm_cfg for c in per_round):
+            return comm_cfg, per_round
+    return comm_cfg, None
+
+
 def build_simulation(n_elements: int, device_mesh: Mesh,
                      comm_cfg: CommConfig | str, swe: SWEConfig = SWEConfig(),
                      seed: int = 0, tune_db_path=None,
@@ -139,43 +173,28 @@ def build_simulation(n_elements: int, device_mesh: Mesh,
 
     ``swe.dt`` is shortened to what the mesh keeps stable
     (:func:`dg_solver.stable_dt`).
+
+    Each phase is a host span (``swe.build.mesh_gen``, ``.partition``,
+    ``.resolve_config``, ``.place``), recorded when tracing is on.
     """
-    mesh = generate_bight_mesh(n_elements, seed=seed)
-    swe = dataclasses.replace(swe, dt=dg_solver.stable_dt(mesh, swe))
+    with obs_trace.span("swe.build.mesh_gen", cat="setup",
+                        n_elements=n_elements):
+        mesh = generate_bight_mesh(n_elements, seed=seed)
+        swe = dataclasses.replace(swe, dt=dg_solver.stable_dt(mesh, swe))
     n_parts = device_mesh.shape["data"]
-    if initial_state is None:
-        initial_state = dg_solver.initial_state(mesh)
-    pm = partition_mesh(mesh, n_parts, np.asarray(initial_state))
+    with obs_trace.span("swe.build.partition", cat="setup", parts=n_parts):
+        if initial_state is None:
+            initial_state = dg_solver.initial_state(mesh)
+        pm = partition_mesh(mesh, n_parts, np.asarray(initial_state))
     round_cfgs = None
     if not isinstance(comm_cfg, CommConfig):
-        from repro.core.collectives import resolve_config
-        from repro.core.communicator import Communicator
-        halo_bytes = int(pm.s_max) * 3 * 4   # (h, hu, hv) f32 per halo element
-        # Worst-case torus hop distance of this partitioning's exchange
-        # pattern — multi-hop edges prefer hop-matched measurements.
-        comm = Communicator(("data",), (n_parts,), topo=topology)
-        edges = [e for r in pm.rounds for e in r]
-        hops = comm.max_hops(edges) if edges else None
-        comm_cfg = resolve_config(comm_cfg, "multi_neighbor", halo_bytes,
-                                  mesh=device_mesh, db_path=tune_db_path,
-                                  hops=hops, objective=objective,
-                                  torus=topology.name if topology else "")
-        # Per-edge selection is a torus feature: the flat mesh keeps PR 4's
-        # single worst-case-hop config (no silent behavior change), and the
-        # double-buffered overlapped engine pipelines all rounds under one
-        # config — don't select what can't be applied.
-        if (pm.rounds and topology is not None
-                and comm_cfg.scheduling != Scheduling.OVERLAPPED):
-            per_round = _select_round_configs(pm.rounds, comm, halo_bytes,
-                                              tune_db_path, objective)
-            # One scheduling discipline per step: unify each round's wire
-            # config with the representative's scheduling.
-            per_round = [dataclasses.replace(c, scheduling=comm_cfg.scheduling)
-                         for c in per_round]
-            if any(c != comm_cfg for c in per_round):
-                round_cfgs = per_round
-    sharding = NamedSharding(device_mesh, P("data"))
-    state = jax.device_put(jnp.asarray(pm.state0, jnp.float32), sharding)
+        with obs_trace.span("swe.build.resolve_config", cat="setup"):
+            comm_cfg, round_cfgs = _resolve_comm(
+                comm_cfg, pm, n_parts, device_mesh, tune_db_path, objective,
+                topology)
+    with obs_trace.span("swe.build.place", cat="setup"):
+        sharding = NamedSharding(device_mesh, P("data"))
+        state = jax.device_put(jnp.asarray(pm.state0, jnp.float32), sharding)
     return Simulation(mesh=mesh, pm=pm, device_mesh=device_mesh,
                       comm_cfg=comm_cfg, swe=swe, state=state,
                       topology=topology, round_cfgs=round_cfgs)
@@ -213,9 +232,14 @@ def make_sim_runner(sim: Simulation, n_inner: int = 10):
              send_idx, send_mask, recv_slot, boundary_idx, t0):
         def inner(carry, i):
             s, t = carry
-            s = step(s[0], t, area[0], normals[0], neigh_idx[0], edge_type[0],
-                     valid[0], send_idx[0], send_mask[0], recv_slot[0],
-                     boundary_idx[0])[None]
+            # this partition's slice of each argument (leading P dim of 1)
+            with obs_trace.scope("swe.args"):
+                local = [a[0] for a in (s, area, normals, neigh_idx,
+                                        edge_type, valid, send_idx,
+                                        send_mask, recv_slot, boundary_idx)]
+            s = step(local[0], t, *local[1:])
+            with obs_trace.scope("swe.args"):
+                s = s[None]
             return (s, t + sim.swe.dt), None
         (state, t), _ = jax.lax.scan(inner, (state, t0), jnp.arange(n_inner))
         return state
@@ -224,14 +248,23 @@ def make_sim_runner(sim: Simulation, n_inner: int = 10):
                        in_specs=in_specs, out_specs=P("data"),
                        check_vma=False)
     fn = jax.jit(sm)
+    segments = itertools.count()
+    scheduling = sim.comm_cfg.scheduling.value
 
     def run(state, t):
-        # Host wall-clock span: one fused dispatch of n_inner steps.  The
-        # dispatch is async, so the span covers launch, not completion —
-        # callers that need completion time block outside.
-        with obs_trace.span("swe.segment", cat="driver", steps=n_inner,
-                            scheduling=sim.comm_cfg.scheduling.value):
-            return fn(state, *arg_list, jnp.asarray(t, jnp.float32))
+        # Host wall-clock spans of one fused dispatch of n_inner steps: the
+        # scalar ``t`` to the device, then the launch.  The dispatch is
+        # async, so the spans cover launch, not completion — callers that
+        # need completion time block outside.
+        n = next(segments)
+        with obs_trace.span("swe.segment", cat="driver", segment=n,
+                            steps=n_inner, scheduling=scheduling):
+            with obs_trace.span("swe.segment.put_t", cat="driver",
+                                segment=n):
+                t = jnp.asarray(t, jnp.float32)
+            with obs_trace.span("swe.segment.launch", cat="driver",
+                                segment=n):
+                return fn(state, *arg_list, t)
 
     return run
 

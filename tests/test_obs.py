@@ -383,3 +383,101 @@ print("OK")
     assert len(inner) >= 2            # multiple chunks per message
     assert all(e["args"]["of"] >= 2 for e in inner)
     assert outer["args"]["hops"] == 1 and outer["args"]["nbytes"] == 1024
+
+
+# ----------------------------------------------------------------------
+# spans on the profiler's clock; scopes in the compiled HLO
+# ----------------------------------------------------------------------
+
+def test_disabled_span_opens_no_profiler_annotation(monkeypatch):
+    def refuse(*a, **k):
+        raise AssertionError("a profiler annotation was opened")
+
+    monkeypatch.setattr(trace, "_ANNOTATION", refuse)
+    s = trace.span("swe.segment", cat="driver", segment=0)
+    assert s is trace._NULL_SPAN
+    with s:
+        pass
+    assert trace.events() == []
+
+
+def test_import_repro_obs_imports_no_jax():
+    import subprocess
+    import sys
+    env = {k: v for k, v in os.environ.items() if k != "REPRO_TRACE"}
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.join(os.path.dirname(__file__), "..", "src")]
+        + env.get("PYTHONPATH", "").split(os.pathsep))
+    out = subprocess.run(
+        [sys.executable, "-c", "import sys, repro.obs; "
+         "print(sorted(m for m in sys.modules if m.split('.')[0] == 'jax'))"],
+        capture_output=True, text=True, env=env, timeout=120, check=True)
+    assert out.stdout.strip() == "[]"
+
+
+def _profile(fn, tmp_path):
+    """Run ``fn`` inside a CPU ``jax.profiler`` trace; return the host
+    plane's events as ``(name, start_ns since the epoch, duration_ns)``."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(str(tmp_path / "plugins" / "profile" / "*" /
+                          "*.xplane.pb"))
+    pd = ProfileData.from_file(path)
+    planes = {p.name: p for p in pd.planes}
+    start = dict(planes["Task Environment"].stats)["profile_start_time"]
+    return [(e.name, start + e.start_ns, e.duration_ns)
+            for line in planes["/host:CPU"].lines for e in line.events]
+
+
+def test_enabled_span_lands_in_profiler_host_plane(tmp_path):
+    trace.configure("1")
+
+    def work():
+        with trace.span("obs.probe", cat="driver", segment=3):
+            sum(range(10000))
+
+    host = _profile(work, tmp_path)
+    (name, start_ns, dur_ns), = [e for e in host if e[0] == "obs.probe"]
+    ev, = [e for e in trace.events() if e["name"] == "obs.probe"]
+    assert ev["args"] == {"segment": 3}
+    # the ring buffer's clock is the profiler's: the two starts agree
+    assert abs(ev["ts"] * 1e3 - start_ns) < 100e3
+    assert ev["dur"] * 1e3 >= dur_ns
+
+
+def test_scope_names_compiled_hlo_and_records_nothing_when_off():
+    import jax
+    import jax.numpy as jnp
+
+    @jax.jit
+    def f(x):
+        with trace.scope("obs.phase", rounds=2):
+            return jnp.sin(x) * 2.0
+
+    text = f.lower(jnp.ones(8)).compile().as_text()
+    assert "/obs.phase/" in text
+    assert trace.events() == []
+
+
+def test_scope_records_structure_event_when_on():
+    import jax
+    import jax.numpy as jnp
+    trace.configure("1")
+
+    @jax.jit
+    def f(x):
+        with trace.scope("obs.phase", rounds=2):
+            return x + 1.0
+
+    f(jnp.ones(4))
+    f(jnp.ones(4))                        # cached: no second trace
+    evs = [e for e in trace.events() if e["name"] == "obs.phase"]
+    assert len(evs) == 1
+    assert evs[0]["cat"] == "phase"
+    assert evs[0]["args"] == {"rounds": 2}

@@ -1,4 +1,6 @@
 """Shallow-water reproduction correctness (the paper's application)."""
+import json
+
 import numpy as np
 import pytest
 
@@ -147,3 +149,81 @@ def test_eq2_eq3_model_properties():
     # extra l_k) is what dominates instead (asserted in 1-2 above).
     assert latmodel.buffered_peak_bw(V5E) < V5E.ici_bw
     assert latmodel.buffered_peak_bw(V5E) > 0.8 * V5E.ici_bw
+
+
+# The solver's phases as named scopes: every phase has instructions in the
+# compiled runner (the halo exchange only where there is a neighbour).
+_SCOPES_CODE = """
+import glob, json, os, re, tempfile
+dump = tempfile.mkdtemp()
+os.environ["XLA_FLAGS"] += (" --xla_dump_to=" + dump + " --xla_dump_hlo_as_text"
+                            " --xla_dump_hlo_module_re=jit_body")
+import jax
+from repro.launch.mesh import make_mesh
+from repro.core.config import OPTIMIZED_CONFIG, CommConfig, Scheduling
+from repro.swe import driver
+cfg = {{"fused": OPTIMIZED_CONFIG,
+        "overlapped": CommConfig(scheduling=Scheduling.OVERLAPPED)}}[{mode!r}]
+sim = driver.build_simulation(500, make_mesh(({n},), ("data",)), cfg)
+driver.make_sim_runner(sim, 5)(sim.state, 0.0)   # compiles, XLA dumps
+text = open(glob.glob(dump + "/*jit_body*after_optimizations.txt")[0]).read()
+found = {{}}
+for name in re.findall(r'op_name="([^"]*)"', text):
+    for part in name.split("/"):
+        if part.startswith("swe."):
+            found[part] = found.get(part, 0) + 1
+print("SCOPES " + json.dumps(found))
+"""
+
+
+@pytest.mark.parametrize("mode,n", [("fused", 1), ("fused", 2),
+                                    ("overlapped", 2)])
+def test_runner_phases_are_scopes_in_compiled_hlo(mode, n):
+    out = run_multidevice(_SCOPES_CODE.format(mode=mode, n=n), n_devices=n)
+    line, = [l for l in out.splitlines() if l.startswith("SCOPES ")]
+    found = json.loads(line[len("SCOPES "):])
+    want = {"swe.args", "swe.gather", "swe.flux", "swe.update"}
+    if n > 1:
+        want.add("swe.exchange")
+    if mode == "overlapped":
+        want |= {"swe.interior", "swe.boundary"}
+    assert want <= set(found), found
+
+
+def test_runner_bitwise_with_tracing_on_and_off():
+    """Tracing changes what is recorded, never the numbers: the same runner
+    gives bitwise the same state with tracing off and on, and with it on
+    each segment leaves its spans, children tagged with its number."""
+    out = run_multidevice("""
+import numpy as np
+from repro.launch.mesh import make_mesh
+from repro.core.config import OPTIMIZED_CONFIG
+from repro.obs import trace
+from repro.swe import driver
+mesh = make_mesh((2,), ("data",))
+states = []
+for mode in ("0", "1"):
+    trace.configure(mode)
+    sim = driver.build_simulation(500, mesh, OPTIMIZED_CONFIG)
+    run = driver.make_sim_runner(sim, 10)
+    s = sim.state
+    for k in range(3):
+        s = run(s, k * 10 * sim.swe.dt)
+    states.append(np.asarray(s))
+assert np.array_equal(states[0], states[1])
+evs = trace.events()
+names = [e["name"] for e in evs if e["cat"] in ("driver", "setup")]
+assert names[:4] == ["swe.build.mesh_gen", "swe.build.partition",
+                     "swe.build.place", "swe.segment.put_t"], names
+for n in range(3):
+    seg = [e for e in evs if e["name"].startswith("swe.segment")
+           and e["args"]["segment"] == n]
+    assert sorted(e["name"] for e in seg) == [
+        "swe.segment", "swe.segment.launch", "swe.segment.put_t"]
+    outer, = [e for e in seg if e["name"] == "swe.segment"]
+    for e in seg:
+        assert outer["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= outer["ts"] + outer["dur"] + 1e-3
+print("TRACING PARITY OK")
+""", n_devices=2)
+    assert "TRACING PARITY OK" in out
