@@ -21,6 +21,13 @@ overlap) differs.
 
 Rusanov (local Lax-Friedrichs) flux; reflective land boundaries; open-sea
 boundary with optional tidal forcing (the bight-of-Abaco scenario).
+
+Layout: the step works component-major, element axis last — the state is
+(3, E), the per-edge arrays (3edges, E) (:func:`edge_major`) — so on a TPU
+the elements lie along the 128-wide lane axis, where row-major (E, 3) rows
+would pad each element's 3 values to a full lane row.  The neighbour gather
+returns (3, 3edges, E), the layout the flux reads, with indices promised in
+bounds (no negative-index wrap).  Halo messages stay (S_max, 3) rows.
 """
 from __future__ import annotations
 
@@ -46,38 +53,54 @@ FLOP_PER_ELEMENT = 260.0
 
 
 def physical_flux(u, n):
-    """u: (..., 3) = (h, hu, hv); n: (..., 2) scaled outward normal."""
-    h = jnp.maximum(u[..., 0], 1e-8)
-    hu, hv = u[..., 1], u[..., 2]
-    un = (hu * n[..., 0] + hv * n[..., 1]) / h      # normal velocity * |n|
+    """u: (3, ...) = (h, hu, hv); n: (2, ...) scaled outward normal."""
+    h = jnp.maximum(u[0], 1e-8)
+    hu, hv = u[1], u[2]
+    un = (hu * n[0] + hv * n[1]) / h                # normal velocity * |n|
     f0 = h * un
-    f1 = hu * un + 0.5 * G * h * h * n[..., 0]
-    f2 = hv * un + 0.5 * G * h * h * n[..., 1]
-    return jnp.stack([f0, f1, f2], axis=-1)
+    f1 = hu * un + 0.5 * G * h * h * n[0]
+    f2 = hv * un + 0.5 * G * h * h * n[1]
+    return jnp.stack([f0, f1, f2])
 
 
 def rusanov(u_l, u_r, n):
     """Rusanov numerical flux through an edge with scaled normal n."""
-    nlen = jnp.maximum(jnp.linalg.norm(n, axis=-1, keepdims=True), 1e-12)
+    nlen = jnp.maximum(jnp.linalg.norm(n, axis=0), 1e-12)
     nhat = n / nlen
-    h_l = jnp.maximum(u_l[..., 0], 1e-8)
-    h_r = jnp.maximum(u_r[..., 0], 1e-8)
-    un_l = (u_l[..., 1] * nhat[..., 0] + u_l[..., 2] * nhat[..., 1]) / h_l
-    un_r = (u_r[..., 1] * nhat[..., 0] + u_r[..., 2] * nhat[..., 1]) / h_r
+    h_l = jnp.maximum(u_l[0], 1e-8)
+    h_r = jnp.maximum(u_r[0], 1e-8)
+    un_l = (u_l[1] * nhat[0] + u_l[2] * nhat[1]) / h_l
+    un_r = (u_r[1] * nhat[0] + u_r[2] * nhat[1]) / h_r
     lam = jnp.maximum(jnp.abs(un_l) + jnp.sqrt(G * h_l),
-                      jnp.abs(un_r) + jnp.sqrt(G * h_r))[..., None]
+                      jnp.abs(un_r) + jnp.sqrt(G * h_r))
     return 0.5 * (physical_flux(u_l, n) + physical_flux(u_r, n)
                   - lam * nlen * (u_r - u_l))
 
 
 def reflect(u, n):
     """Reflective (land) ghost state: mirror the normal momentum."""
-    nlen = jnp.maximum(jnp.linalg.norm(n, axis=-1, keepdims=True), 1e-12)
+    nlen = jnp.maximum(jnp.linalg.norm(n, axis=0), 1e-12)
     nhat = n / nlen
-    qn = u[..., 1] * nhat[..., 0] + u[..., 2] * nhat[..., 1]
-    return jnp.stack([u[..., 0],
-                      u[..., 1] - 2 * qn * nhat[..., 0],
-                      u[..., 2] - 2 * qn * nhat[..., 1]], axis=-1)
+    qn = u[1] * nhat[0] + u[2] * nhat[1]
+    return jnp.stack([u[0],
+                      u[1] - 2 * qn * nhat[0],
+                      u[2] - 2 * qn * nhat[1]])
+
+
+def edge_major(normals, neigh_idx, edge_type):
+    """The per-element edge arrays in the step's layout, element axis last:
+    ``normals`` (..., E, 3edges, 2) -> (..., 2, 3edges, E), ``neigh_idx``
+    and ``edge_type`` (..., E, 3edges) -> (..., 3edges, E).  Takes numpy or
+    jax arrays."""
+    return (normals.swapaxes(-1, -3), neigh_idx.swapaxes(-1, -2),
+            edge_type.swapaxes(-1, -2))
+
+
+def take(a, idx):
+    """``a[..., idx]`` for indices that are in bounds by construction
+    (``partition_mesh``): no negative-index wrap, no clamp."""
+    return a.at[..., idx].get(mode="promise_in_bounds",
+                              wrap_negative_indices=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -104,10 +127,37 @@ def stable_dt(mesh, swe: SWEConfig = SWEConfig(), cfl: float = 0.5) -> float:
 def make_step_fn(pm: PartitionedMesh, comm_cfg: CommConfig, axis: str = "data",
                  swe: SWEConfig = SWEConfig(), topology=None,
                  round_cfgs=None):
-    """Returns step(state, halo_arrays..., boundary_idx) for use inside
-    shard_map.
+    """Returns step(state, t, area, normals, neigh_idx, edge_type, valid,
+    send_idx, send_mask, recv_slot, boundary_idx) -> new state, for use
+    inside shard_map, on ``PartitionedMesh``'s row-major arrays: this
+    device's partition slice (leading P dim removed), ``state`` (E_max, 3).
 
-    All arrays are this device's partition slice (leading P dim removed).
+    A wrapper that lays the arguments out for :func:`make_step_core` and
+    the result back; the segment runner calls the core directly.
+    """
+    core = make_step_core(pm, comm_cfg, axis, swe, topology, round_cfgs)
+
+    def step(state, t, area, normals, neigh_idx, edge_type, valid, *rest):
+        return core(state.T, t, area,
+                    *edge_major(normals, neigh_idx, edge_type),
+                    valid, *rest).T
+
+    return step
+
+
+def make_step_core(pm: PartitionedMesh, comm_cfg: CommConfig,
+                   axis: str = "data", swe: SWEConfig = SWEConfig(),
+                   topology=None, round_cfgs=None):
+    """Returns step(state, t, area, normals, neigh_idx, edge_type, valid,
+    send_idx, send_mask, recv_slot, boundary_idx) -> new state in the
+    component-major layout, for use inside shard_map.
+
+    All arrays are this device's partition slice (leading P dim removed),
+    with the element axis last: ``state`` (3, E_max); ``area``, ``valid``
+    (E_max,); ``normals`` (2, 3edges, E_max), ``neigh_idx`` and
+    ``edge_type`` (3edges, E_max) (:func:`edge_major`).  The exchange
+    arrays keep ``PartitionedMesh``'s layout.
+
     ``comm_cfg.scheduling == OVERLAPPED`` selects the interior/boundary-split
     step (interior compute carries no dependency on the exchange); all other
     schedules use the exchange-then-update step.  Both are bitwise-equal.
@@ -127,7 +177,8 @@ def make_step_fn(pm: PartitionedMesh, comm_cfg: CommConfig, axis: str = "data",
                     else comm_cfg)
 
     def payloads_for(state, send_idx, send_mask):
-        return [state[send_idx[r]] * send_mask[r][:, None]
+        """Each round's (S_max, 3) rows of the elements it sends."""
+        return [(take(state, send_idx[r]) * send_mask[r]).T
                 for r in range(pm.n_rounds)]
 
     def fold_round(halo, recv_slot_r, recv):
@@ -179,34 +230,37 @@ def make_step_fn(pm: PartitionedMesh, comm_cfg: CommConfig, axis: str = "data",
         return halo
 
     def edge_fluxes(u_own, u_n, n, edge_type, t):
-        """Rusanov flux per edge; shape-generic over the leading element dim.
+        """Rusanov flux per edge; shape-generic over the trailing element
+        axis.
 
-        ``u_own``: (..., 3) element states; ``u_n``: (..., 3edges, 3) neighbor
-        states; ``n``: (..., 3edges, 2) scaled normals.
+        ``u_own``: (3, ...) element states; ``u_n``: (3, 3edges, ...)
+        neighbour states; ``n``: (2, 3edges, ...) scaled normals;
+        ``edge_type``: (3edges, ...).
         """
-        u = jnp.broadcast_to(u_own[..., None, :], u_n.shape)
+        u = jnp.broadcast_to(u_own[:, None], u_n.shape)
         # ghost states per edge type
         u_land = reflect(u, n)
         h_sea = swe.h_sea + swe.tidal_amplitude * jnp.sin(swe.tidal_omega * t)
-        u_sea = jnp.stack([jnp.broadcast_to(h_sea, u[..., 0].shape),
-                           u[..., 1], u[..., 2]], axis=-1)
-        u_r = jnp.where(edge_type[..., None] == 1, u_land,
-                        jnp.where(edge_type[..., None] == 2, u_sea, u_n))
-        return rusanov(u, u_r, n)                      # (..., 3edges, 3)
+        u_sea = jnp.stack([jnp.broadcast_to(h_sea, u[0].shape), u[1], u[2]])
+        u_r = jnp.where(edge_type == 1, u_land,
+                        jnp.where(edge_type == 2, u_sea, u_n))
+        return rusanov(u, u_r, n)                      # (3, 3edges, ...)
 
     def gather(state, halo, neigh_idx):
         """Each element's three neighbour states, from its own partition or
-        the halo: (..., 3edges, 3)."""
-        ext = jnp.concatenate([state, halo], axis=0)   # (E_max+H_max, 3)
-        return ext[neigh_idx]
+        the halo, (3, 3edges, ...): element axis last, as the flux reads
+        it."""
+        ext = jnp.concatenate([state, halo.T], axis=1)  # (3, E_max+H_max)
+        return take(ext, neigh_idx)
 
-    def apply_update(state_rows, f, area_rows, valid_rows):
-        div = jnp.sum(f, axis=-2)                      # (..., 3)
-        new = state_rows - swe.dt / area_rows[..., None] * div
-        new = new * valid_rows[..., None]
+    def apply_update(state, f, area, valid):
+        """``state`` (3, ...), ``f`` (3, 3edges, ...), ``area`` and
+        ``valid`` (...): the new state, (3, ...)."""
+        div = jnp.sum(f, axis=1)                       # (3, ...)
+        new = state - swe.dt / area * div
+        new = new * valid
         # keep water depth positive
-        return new.at[..., 0].set(
-            jnp.maximum(new[..., 0], 1e-6) * valid_rows)
+        return new.at[0].set(jnp.maximum(new[0], 1e-6) * valid)
 
     # Phases are named scopes (``swe.gather``, ``swe.flux``, ``swe.update``,
     # ``swe.exchange``): every device operation of the step carries its
@@ -250,14 +304,15 @@ def make_step_fn(pm: PartitionedMesh, comm_cfg: CommConfig, axis: str = "data",
         with obs_trace.scope("swe.boundary"):
             b = boundary_idx
             with obs_trace.scope("swe.gather"):
-                u_b = gather(state, halo, neigh_idx[b])
+                u_b = gather(state, halo, take(neigh_idx, b))
                 state_b, normals_b, edge_type_b, area_b, valid_b = (
-                    a[b] for a in (state, normals, edge_type, area, valid))
+                    take(a, b) for a in (state, normals, edge_type, area,
+                                         valid))
             with obs_trace.scope("swe.flux"):
                 f_b = edge_fluxes(state_b, u_b, normals_b, edge_type_b, t)
             with obs_trace.scope("swe.update"):
                 new_b = apply_update(state_b, f_b, area_b, valid_b)
-                return new.at[b].set(new_b)
+                return new.at[:, b].set(new_b)
 
     if comm_cfg.scheduling == Scheduling.OVERLAPPED:
         return step_overlapped

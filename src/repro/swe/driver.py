@@ -9,7 +9,7 @@ Three execution modes, mirroring the paper's §3.1/§5 scheduling comparison:
   into interior/boundary element passes around a double-buffered halo
   exchange, so interior compute carries no dependency on the in-flight
   permutes (``make_sim_runner`` serves this mode too — the split lives in
-  ``dg_solver.make_step_fn``).
+  ``dg_solver.make_step_core``).
 - **host** ("MPI+PCIe baseline"): each phase is a separate dispatch — the
   exchange is staged through host-visible buffers between two compiled
   programs, paying 2·l_k per step exactly like the paper's baseline where the
@@ -31,7 +31,7 @@ from repro.core.config import CommConfig, Scheduling
 from repro.core import latmodel
 from repro.obs import trace as obs_trace
 from repro.swe import dg_solver
-from repro.swe.dg_solver import SWEConfig, make_step_fn
+from repro.swe.dg_solver import SWEConfig, make_step_core
 from repro.swe.mesh_gen import Mesh as SweMesh, generate_bight_mesh
 from repro.swe.partition import PartitionedMesh, partition_mesh
 
@@ -201,14 +201,19 @@ def build_simulation(n_elements: int, device_mesh: Mesh,
 
 
 def _static_args(sim: Simulation):
+    """The step's static arguments on the devices, each with its leading P
+    dim, the per-element edge arrays laid out element axis last once here
+    (:func:`dg_solver.edge_major`)."""
     pm = sim.pm
     sharding = NamedSharding(sim.device_mesh, P("data"))
     put = lambda a, dt=jnp.float32: jax.device_put(jnp.asarray(a, dt), sharding)
+    normals, neigh_idx, edge_type = dg_solver.edge_major(
+        pm.normals, pm.neigh_idx, pm.edge_type)
     return dict(
         area=put(pm.area),
-        normals=put(pm.normals),
-        neigh_idx=put(pm.neigh_idx, jnp.int32),
-        edge_type=put(pm.edge_type, jnp.int32),
+        normals=put(normals),
+        neigh_idx=put(neigh_idx, jnp.int32),
+        edge_type=put(edge_type, jnp.int32),
         valid=put(pm.valid),
         send_idx=put(pm.send_idx, jnp.int32),
         send_mask=put(pm.send_mask),
@@ -217,37 +222,54 @@ def _static_args(sim: Simulation):
     )
 
 
-def make_sim_runner(sim: Simulation, n_inner: int = 10):
-    """Fused/overlapped runner: `run(state, t)` advances n_inner steps in one
-    dispatch (the interior/boundary split of overlapped scheduling lives
-    inside the step function)."""
-    pm = sim.pm
-    step = make_step_fn(pm, sim.comm_cfg, "data", sim.swe,
-                        topology=sim.topology, round_cfgs=sim.round_cfgs)
+def _segment_program(sim: Simulation, n_steps: int):
+    """One compiled dispatch of ``n_steps`` steps: ``fn(state, *static,
+    t)`` with ``state`` ``(P, E_max, 3)`` in and out, and ``static``, the
+    arguments of :func:`_static_args`, which it returns beside it.
+
+    The steps run on the component-major layout of
+    :func:`dg_solver.make_step_core`: the state is transposed to its three
+    component rows once before the scan and back once after it, both under
+    the ``swe.args`` scope with the per-partition squeezes.  The scan
+    carries the three ``(E_max,)`` rows: a ``(3, E_max)`` carry would take
+    the row-major layout of the segment's input, which on a TPU pads each
+    element's three values to a 128-lane row and costs a relayout in and
+    out of every step."""
+    step = make_step_core(sim.pm, sim.comm_cfg, "data", sim.swe,
+                          topology=sim.topology, round_cfgs=sim.round_cfgs)
     args = _static_args(sim)
     in_specs = (P("data"),) + (P("data"),) * len(args) + (P(),)
-    arg_list = list(args.values())
 
-    def body(state, area, normals, neigh_idx, edge_type, valid,
-             send_idx, send_mask, recv_slot, boundary_idx, t0):
-        def inner(carry, i):
+    def body(state, *rest):
+        *static, t0 = rest
+        # this partition's slice of each argument (leading P dim of 1)
+        with obs_trace.scope("swe.args"):
+            s = tuple(state[0].T)
+            local = [a[0] for a in static]
+
+        def inner(carry, _):
             s, t = carry
-            # this partition's slice of each argument (leading P dim of 1)
-            with obs_trace.scope("swe.args"):
-                local = [a[0] for a in (s, area, normals, neigh_idx,
-                                        edge_type, valid, send_idx,
-                                        send_mask, recv_slot, boundary_idx)]
-            s = step(local[0], t, *local[1:])
-            with obs_trace.scope("swe.args"):
-                s = s[None]
-            return (s, t + sim.swe.dt), None
-        (state, t), _ = jax.lax.scan(inner, (state, t0), jnp.arange(n_inner))
-        return state
+            # the stack is what the gather's table is built from
+            with obs_trace.scope("swe.gather"):
+                s = jnp.stack(s)
+            return (tuple(step(s, t, *local)), t + sim.swe.dt), None
+        (s, _), _ = jax.lax.scan(inner, (s, t0), length=n_steps)
+        with obs_trace.scope("swe.args"):
+            return jnp.stack(s, axis=-1)[None]
 
     sm = jax.shard_map(body, mesh=sim.device_mesh,
                        in_specs=in_specs, out_specs=P("data"),
                        check_vma=False)
-    fn = jax.jit(sm)
+    return jax.jit(sm), args
+
+
+def make_sim_runner(sim: Simulation, n_inner: int = 10):
+    """Fused/overlapped runner: `run(state, t)` advances n_inner steps in one
+    dispatch (the interior/boundary split of overlapped scheduling lives
+    inside the step function); ``state`` is ``(P, E_max, 3)`` in and out
+    (:func:`_segment_program`)."""
+    fn, args = _segment_program(sim, n_inner)
+    arg_list = list(args.values())
     segments = itertools.count()
     scheduling = sim.comm_cfg.scheduling.value
 
@@ -272,11 +294,9 @@ def make_sim_runner(sim: Simulation, n_inner: int = 10):
 def make_host_scheduled_runner(sim: Simulation):
     """Paper-baseline: communication staged through a host-visible buffer
     between two separately dispatched programs (2 dispatches / step)."""
-    pm = sim.pm
     swe = sim.swe
-    step_full = make_step_fn(pm, sim.comm_cfg, "data", sim.swe,
-                             topology=sim.topology, round_cfgs=sim.round_cfgs)
-    args = _static_args(sim)
+    # phase 2: full step (exchange + update) as its own dispatch
+    step_sm, args = _segment_program(sim, 1)
     arg_list = list(args.values())
 
     # phase 1: gather the send payloads (what the paper's communication
@@ -288,19 +308,6 @@ def make_host_scheduled_runner(sim: Simulation):
     gather_sm = jax.jit(jax.shard_map(
         gather, mesh=sim.device_mesh,
         in_specs=(P("data"), P("data"), P("data")), out_specs=P("data"),
-        check_vma=False))
-
-    # phase 2: full step (exchange + update) as its own dispatch
-    def phase2(state, area, normals, neigh_idx, edge_type, valid,
-               send_idx, send_mask, recv_slot, boundary_idx, t0):
-        s = step_full(state[0], t0, area[0], normals[0], neigh_idx[0],
-                      edge_type[0], valid[0], send_idx[0], send_mask[0],
-                      recv_slot[0], boundary_idx[0])[None]
-        return s
-
-    in_specs = (P("data"),) + (P("data"),) * len(arg_list) + (P(),)
-    step_sm = jax.jit(jax.shard_map(
-        phase2, mesh=sim.device_mesh, in_specs=in_specs, out_specs=P("data"),
         check_vma=False))
 
     class Runner:
